@@ -22,14 +22,17 @@ import sys
 import traceback
 
 
-def _emit(mod) -> None:
+def _emit(mod) -> bool:
+    """Print ``mod``'s rows; False (after an ERROR row) when it raised."""
     try:
         for name, value, derived in mod.run():
             print(f"{name},{value},{str(derived).replace(',', ';')}")
+        return True
     except Exception as e:
         print(f"{mod.__name__}.ERROR,-1,{type(e).__name__}: "
               f"{str(e)[:120]}".replace(",", ";"))
         traceback.print_exc(file=sys.stderr)
+        return False
 
 
 def main() -> None:
@@ -38,7 +41,9 @@ def main() -> None:
                             replicate, roofline, table1_pipeline,
                             table2_modules, table3_resources,
                             trace_pipeline)
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     smoke = "--smoke" in sys.argv[1:]
     print("name,value,derived")
     if smoke:
@@ -137,16 +142,20 @@ def main() -> None:
     # replan/replicate/devices/faults/overload last: their thread pools,
     # serving loops, and open-loop load generators are the noisiest
     # neighbors for the wall-clock benchmarks that precede them
-    for mod in (table1_pipeline, table2_modules, table3_resources,
-                fig4_callgraph, fusion, roofline, analysis, trace_pipeline,
-                replan, replicate, devices, faults, overload, decode):
-        _emit(mod)
+    ok = [_emit(mod) for mod in (
+        table1_pipeline, table2_modules, table3_resources, fig4_callgraph,
+        fusion, roofline, analysis, trace_pipeline, replan, replicate,
+        devices, faults, overload, decode)]
     try:
         path = table1_pipeline.write_bench_json()
         print(f"bench_json,0,{path}")
     except Exception as e:
         print(f"bench_json.ERROR,-1,{type(e).__name__}: "
               f"{str(e)[:120]}".replace(",", ";"))
+        traceback.print_exc(file=sys.stderr)
+        ok.append(False)
+    if not all(ok):
+        sys.exit(1)
 
 
 if __name__ == "__main__":

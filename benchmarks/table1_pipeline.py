@@ -238,7 +238,7 @@ def serving_run(n_requests: int = 24, max_batch: int = 4) -> list[tuple[str, flo
     from repro.launch.serve import serve_pipeline_demo
 
     stats = serve_pipeline_demo(n_requests=n_requests, max_batch=max_batch,
-                                max_wait_ms=4.0, size=(64, 96))
+                                max_wait_ms=4.0, size=(64, 96)).stats
     lat = stats["latency_ms"]
     return [
         ("table1.serving.requests", stats["requests_served"],

@@ -16,18 +16,20 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from jax.sharding import AxisType
+
 from repro.core import (CourierIR, Node, linear_ir, partition_optimal,
                         partition_paper, pipeline_microbatches)
+from repro.launch.compile_cache import enable_compile_cache
 
-try:                                    # AxisType only exists on jax>=0.5
-    from jax.sharding import AxisType
-    _mesh = lambda shape, axes: jax.make_mesh(
-        shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-except ImportError:
-    _mesh = lambda shape, axes: jax.make_mesh(shape, axes)
+
+def _mesh(shape, axes):
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def main():
+    enable_compile_cache()
     mesh = _mesh((4,), ("stage",))
 
     # A 12-layer stack whose second half is 4x wider (cost-heterogeneous,

@@ -15,10 +15,12 @@ import numpy as np
 
 from repro.core import courier_offload
 from repro.core.tracer import Library
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.harris import corner_harris_demo, make_harris_db
 
 
 def main():
+    enable_compile_cache()
     # The "running binary": user code over a library namespace, never edited.
     db = make_harris_db(with_hw=True)
     lib = Library(db)

@@ -12,12 +12,14 @@ from dataclasses import replace
 import jax
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import build
 from repro.checkpoint import CheckpointStore
 from repro.runtime import FaultTolerantDriver
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--batch", type=int, default=8)

@@ -7,8 +7,9 @@ no synthesis report, so the "hardware" estimate is an analytical roofline:
 
     t = max(flops / PEAK_FLOPS, bytes / HBM_BW)  (+ collective term)
 
-using TPU v5e constants (per task spec): 197 TFLOP/s bf16 per chip,
-819 GB/s HBM bandwidth, ~50 GB/s per ICI link.
+using TPU v5e constants by default: 197 TFLOP/s bf16 per chip, 819 GB/s
+HBM bandwidth, ~50 GB/s per ICI link.  Per-device constants are keyed by
+the ``device_kind`` JAX reports (:data:`DEVICE_CLASSES`).
 
 Both sources feed the same ``NodeCost`` record so the Pipeline Generator's
 balanced partitioning (paper Sect. III-B.4) is agnostic to where a time
@@ -24,11 +25,18 @@ from typing import Callable, Sequence
 import numpy as np
 
 # ---- TPU v5e hardware constants (per chip) -------------------------------- #
+# Published peaks of one TPU v5e chip (Google Cloud documentation, "TPU v5e"):
+# 197 TFLOP/s bf16, 16 GB of HBM at 819 GB/s.
 PEAK_FLOPS_BF16 = 197e12        # FLOP/s
 HBM_BW = 819e9                  # bytes/s
 ICI_BW_PER_LINK = 50e9          # bytes/s per link (per direction)
 HBM_BYTES = 16 * 1024**3        # 16 GiB HBM per chip
-VMEM_BYTES = 128 * 1024**2      # ~128 MiB VMEM per core (v5e ballpark)
+# Scoped VMEM every Pallas kernel here is compiled with
+# (``repro.kernels.backend.compiler_params``); the fusion model, the plan
+# verifier and the fused kernel's row-block search check working sets
+# against the same figure.  A v5e core has 128 MiB of VMEM; Mosaic's default scoped
+# limit is 16 MiB, too small for cvtColor's 3-on-lanes block at 1920 wide.
+VMEM_BYTES = 64 * 1024**2
 MXU_TILE = (128, 128)           # systolic array tile
 LANE = 128                      # vector lane width
 SUBLANE = 8
@@ -63,20 +71,26 @@ class DeviceClass:
     vmem_bytes: int = VMEM_BYTES
 
 
+# keyed by ``jax.Device.device_kind``
 DEVICE_CLASSES: dict[str, DeviceClass] = {
-    "tpu": DeviceClass("tpu"),
-    # A100-ish ballpark: ~2x the v5e HBM bw, ~1.6x bf16 flops
-    "gpu": DeviceClass("gpu", peak_flops=312e12, hbm_bw=1.6e12,
-                       ici_bw=300e9),
+    # TPU v5e: the published peaks above
+    "TPU v5 lite": DeviceClass("TPU v5 lite"),
     # one beefy host core + DDR: the "software filter on a CPU core" class
     "cpu": DeviceClass("cpu", peak_flops=1e11, hbm_bw=3e10, ici_bw=1e10,
                        xfer_bw=30e9, vmem_bytes=32 * 1024**2),
 }
 
 
-def device_class(platform: str) -> DeviceClass:
-    """Roofline constants for a platform name (unknown → TPU defaults)."""
-    return DEVICE_CLASSES.get(str(platform).lower(), DEVICE_CLASSES["tpu"])
+def device_class(kind: str) -> DeviceClass:
+    """Roofline constants for a ``device_kind``; an unknown kind is an
+    error, never a silent default."""
+    try:
+        return DEVICE_CLASSES[kind]
+    except KeyError:
+        raise ValueError(
+            f"no roofline constants for device kind {kind!r}; add its "
+            f"published peaks to DEVICE_CLASSES "
+            f"(known: {sorted(DEVICE_CLASSES)})") from None
 
 
 def transfer_ms(nbytes: float, bw_bytes_per_s: float = HOST_XFER_BW) -> float:

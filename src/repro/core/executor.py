@@ -91,6 +91,7 @@ from typing import Any, Callable, Iterable, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 __all__ = ["PipelineExecutor", "ExecutorStats", "StageCounters",
            "PendingToken", "SubmitError", "ExecutorClosed"]
@@ -143,6 +144,9 @@ class StageCounters:
     # pinning is not in effect (xfer_ms stays 0 and profiler samples carry
     # no device ordinal).
     devices: list = field(default_factory=list)
+    # groups this stage EXECUTED per jax device id, read off the devices
+    # that hold the stage's outputs (device-pinned replicas only)
+    ran_on: dict = field(default_factory=dict)
 
     def as_dict(self) -> dict:
         return {"issued": self.issued, "tokens": self.tokens,
@@ -151,7 +155,8 @@ class StageCounters:
                 "exec_ms": round(self.exec_ms, 4),
                 "xfer_ms": round(self.xfer_ms, 4),
                 "replicas": self.replicas,
-                "devices": list(self.devices)}
+                "devices": list(self.devices),
+                "ran_on": {str(k): v for k, v in sorted(self.ran_on.items())}}
 
 
 @dataclass
@@ -211,6 +216,14 @@ class ExecutorStats:
             "throughput_tps": round(self.throughput_tps, 2),
             "per_stage": [s.as_dict() for s in self.per_stage],
         }
+
+
+@jax.jit
+def _set_row(v: jax.Array, row: jax.Array, a: Any) -> jax.Array:
+    """``v.at[row].set(a)`` with the row traced: one executable per group
+    shape, not one per seat (an eager ``.at[int]`` compiles per index, and
+    the seam pays it under the executor lock)."""
+    return v.at[row].set(a)
 
 
 # --------------------------------------------------------------------------- #
@@ -763,7 +776,8 @@ class PipelineExecutor:
                 row = g.size
                 # functional row update — async dispatch, completes (as a
                 # program order write) before the worker's sealed read
-                g.env = {k: v.at[row].set(a) if hasattr(v, "at") else v
+                g.env = {k: _set_row(v, np.int32(row), a)
+                         if hasattr(v, "at") else v
                          for (k, v), a in zip(g.env.items(), toks)}
                 g.size += 1
                 self._occupancy += 1
@@ -799,7 +813,8 @@ class PipelineExecutor:
             if idx in g.evicted:
                 return True
             if self.pad_token is not None:
-                g.env = {k: (v.at[idx].set(p) if hasattr(v, "at") else v)
+                g.env = {k: (_set_row(v, np.int32(idx), p)
+                             if hasattr(v, "at") else v)
                          for (k, v), p in zip(g.env.items(), self.pad_token)}
             g.evicted[idx] = error if error is not None else RuntimeError(
                 "token evicted at the batch seam")
@@ -1239,6 +1254,9 @@ class PipelineExecutor:
                     xfer = 0.0
                 g.env = jax.block_until_ready(g.fns[si](g.env))
                 ms = (time.perf_counter() - t0) * 1e3
+                ran_on = ({d.id for leaf in jax.tree.leaves(g.env)
+                           for d in leaf.devices()}
+                          if dev is not None else ())
                 if self.profiler is not None:
                     # the profiler measures SERVICE time — staging
                     # included, matching the replicated_bottleneck_ms
@@ -1249,8 +1267,11 @@ class PipelineExecutor:
                 with self._lock:
                     # counters are DISJOINT: exec_ms is the stage body
                     # alone, xfer_ms the staging hop (sum = service)
-                    self._stats.per_stage[si].exec_ms += ms - xfer
-                    self._stats.per_stage[si].xfer_ms += xfer
+                    c = self._stats.per_stage[si]
+                    c.exec_ms += ms - xfer
+                    c.xfer_ms += xfer
+                    for d in ran_on:
+                        c.ran_on[d] = c.ran_on.get(d, 0) + 1
                 return True
             except BaseException as e:
                 action = self._on_stage_error(si, w, g, e, inj_ord)

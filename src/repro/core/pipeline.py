@@ -281,12 +281,11 @@ class StageFn:
     @property
     def compiles(self) -> int:
         """Number of distinct executables compiled for this stage."""
-        if not self.jitted:
-            return 0
-        try:
-            return self._fn._cache_size()
-        except AttributeError:          # non-jit fallback / older jax
-            return 0
+        return self._fn._cache_size() if self.jitted else 0
+
+    def lower(self, env: dict):
+        """``jax.jit(...).lower`` of the stage for ``env`` (jitted stages)."""
+        return self._fn.lower(env)
 
 
 def make_stage_fns(ir: CourierIR, db: ModuleDatabase, plan: PipelinePlan,

@@ -216,12 +216,18 @@ class DeviceSpec:
     """One placeable device: ordinal + platform + optional topology."""
 
     ordinal: int                       # index into the inventory
-    platform: str = "cpu"              # "tpu" | "gpu" | "cpu"
+    platform: str = "cpu"              # "tpu" | "cpu"
     device_id: int | None = None       # backend device id (jax.Device.id)
     coord: tuple[int, ...] | None = None   # mesh/pod coordinate when known
     speed: float = 1.0                 # relative throughput vs class baseline
+    kind: str = "cpu"                  # jax.Device.device_kind (peaks key)
 
     def __post_init__(self) -> None:
+        # the kind picks the roofline peaks: a TPU spec left with the CPU
+        # default kind would be costed as a host core
+        if (self.platform == "cpu") != (self.kind == "cpu"):
+            raise ValueError(f"device kind {self.kind!r} does not match "
+                             f"platform {self.platform!r}")
         if self.coord is not None:
             object.__setattr__(self, "coord",
                                tuple(int(c) for c in self.coord))
@@ -270,7 +276,7 @@ class DeviceInventory:
         specs = [DeviceSpec(ordinal=i, platform=str(d.platform),
                             device_id=int(getattr(d, "id", i)),
                             coord=tuple(getattr(d, "coords", None) or ())
-                            or None)
+                            or None, kind=str(d.device_kind))
                  for i, d in enumerate(devs)]
         return cls(specs, jax_devices=devs)
 
@@ -285,21 +291,27 @@ class DeviceInventory:
             d = arr[idx]
             specs.append(DeviceSpec(ordinal=i, platform=str(d.platform),
                                     device_id=int(getattr(d, "id", i)),
-                                    coord=tuple(int(c) for c in idx)))
+                                    coord=tuple(int(c) for c in idx),
+                                    kind=str(d.device_kind)))
             devs.append(d)
         return cls(specs, jax_devices=devs)
 
     @classmethod
-    def host(cls, n: int, platform: str = "cpu") -> "DeviceInventory":
-        """Synthetic n-device inventory (planner tests / dry planning).
+    def host(cls, n: int, kind: str = "cpu") -> "DeviceInventory":
+        """Synthetic n-device inventory of ``kind`` devices (a
+        ``jax.Device.device_kind``: ``"cpu"`` or a TPU's, such as
+        ``"TPU v5 lite"``) for planner tests and dry planning.
 
         Carries no ``jax.Device`` objects, so executors treat every
         ordinal as the default device (planning-only inventory).  Each
         spec gets a synthetic stable ``device_id`` so :meth:`refresh` can
         match survivors across a :meth:`drop` re-densification.
         """
-        return cls([DeviceSpec(ordinal=i, platform=platform, device_id=i)
-                    for i in range(n)])
+        if kind != "cpu" and not kind.startswith("TPU"):
+            raise ValueError(f"no platform known for device kind {kind!r}")
+        platform = "cpu" if kind == "cpu" else "tpu"
+        return cls([DeviceSpec(ordinal=i, platform=platform, device_id=i,
+                               kind=kind) for i in range(n)])
 
     # -- queries ------------------------------------------------------------ #
     def __len__(self) -> int:
@@ -328,9 +340,9 @@ class DeviceInventory:
         return self._jax[ordinal]
 
     def device_class(self, ordinal: int):
-        """Roofline constants for the device's platform class."""
+        """Roofline constants for the device's ``device_kind``."""
         from .costmodel import device_class
-        return device_class(self.spec(ordinal).platform)
+        return device_class(self.spec(ordinal).kind)
 
     @property
     def homogeneous(self) -> bool:

@@ -36,17 +36,6 @@ __all__ = ["stack_stage_params", "stage_apply", "spmd_pipeline_fn",
            "pipeline_microbatches"]
 
 
-def _shard_map(fn, mesh, in_specs, out_specs):
-    """shard_map across jax versions: top-level (>=0.5, check_vma) vs
-    jax.experimental.shard_map (0.4.x, check_rep)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
-
-
 # --------------------------------------------------------------------------- #
 # Parameter staging
 # --------------------------------------------------------------------------- #
@@ -167,11 +156,11 @@ def pipeline_microbatches(mesh, block_fn: Callable, layer_params: Any,
     fn = spmd_pipeline_fn(block_fn, n_stages, axis_name)
 
     mb_spec = P(None, batch_axis) if batch_axis else P()
-    shmap = _shard_map(
+    shmap = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(axis_name), staged),
                   P(), mb_spec),
-        out_specs=P(axis_name))
+        out_specs=P(axis_name), check_vma=False)
     out = shmap(staged, lengths, xs)           # [S*M, mb, ...] stacked by stage
     # every stage contributed an [M, ...] buffer; only the last stage's holds
     # the retired tokens (serial_in_order exit)

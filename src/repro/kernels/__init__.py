@@ -2,8 +2,10 @@
 
 Each kernel ships three layers (per task spec):
   <name>.py  — pl.pallas_call + explicit BlockSpec VMEM tiling
-  ops.py     — jit'd public wrappers with the hw/sw dispatch switch
+  ops.py     — jit'd public wrappers and database rows
   ref.py     — pure-jnp oracles (assert_allclose targets)
+backend.py decides where kernels run: compiled on a TPU, interpreted only
+on the CPU.
 """
 from . import ops, ref
 from .flash_attention import flash_attention

@@ -29,6 +29,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from .backend import compiler_params, interpret_mode
+
 DEFAULT_BQ = 512
 DEFAULT_BK = 512
 NEG_INF = -1e30
@@ -49,10 +51,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
 
     def body(j, carry):
         acc, m_i, l_i = carry
-        k = pl.load(k_ref, (pl.ds(0, 1), pl.ds(j * bk, bk), slice(None))
-                    )[0].astype(jnp.float32)            # [bk, hd]
-        v = pl.load(v_ref, (pl.ds(0, 1), pl.ds(j * bk, bk), slice(None))
-                    )[0].astype(jnp.float32)
+        k = k_ref[0, pl.ds(j * bk, bk), :].astype(jnp.float32)   # [bk, hd]
+        v = v_ref[0, pl.ds(j * bk, bk), :].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))   # [bq, bk]
         k_pos = j * bk + jax.lax.iota(jnp.int32, bk)
         d = q_pos[:, None] - k_pos[None, :]
@@ -109,7 +109,8 @@ def _fwd(q, k, v, *, causal, window, bq, bk, interpret):
             jax.ShapeDtypeStruct((BH, T, hd), q.dtype),
             jax.ShapeDtypeStruct((BH, T), jnp.float32),
         ],
-        interpret=interpret,
+        compiler_params=compiler_params(),
+        interpret=interpret_mode(interpret),
     )(q, k, v)
 
 
@@ -128,10 +129,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
     q_pos = qi * bq + jax.lax.iota(jnp.int32, bq)
 
     def body(j, dq):
-        k = pl.load(k_ref, (pl.ds(0, 1), pl.ds(j * bk, bk),
-                            slice(None)))[0].astype(jnp.float32)
-        v = pl.load(v_ref, (pl.ds(0, 1), pl.ds(j * bk, bk),
-                            slice(None)))[0].astype(jnp.float32)
+        k = k_ref[0, pl.ds(j * bk, bk), :].astype(jnp.float32)
+        v = v_ref[0, pl.ds(j * bk, bk), :].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))
         k_pos = j * bk + jax.lax.iota(jnp.int32, bk)
         d = q_pos[:, None] - k_pos[None, :]
@@ -164,12 +163,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     def body(i, carry):
         dk, dv = carry
-        q = pl.load(q_ref, (pl.ds(0, 1), pl.ds(i * bq, bq), slice(None))
-                    )[0].astype(jnp.float32) * scale
-        do = pl.load(do_ref, (pl.ds(0, 1), pl.ds(i * bq, bq), slice(None))
-                     )[0].astype(jnp.float32)
-        lse = pl.load(lse_ref, (pl.ds(0, 1), pl.ds(i * bq, bq)))[0]
-        delta = pl.load(delta_ref, (pl.ds(0, 1), pl.ds(i * bq, bq)))[0]
+        q = q_ref[0, pl.ds(i * bq, bq), :].astype(jnp.float32) * scale
+        do = do_ref[0, pl.ds(i * bq, bq), :].astype(jnp.float32)
+        lse = lse_ref[0, pl.ds(i * bq, bq)]
+        delta = delta_ref[0, pl.ds(i * bq, bq)]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # [bq, bk]
         q_pos = i * bq + jax.lax.iota(jnp.int32, bq)
         d = q_pos[:, None] - k_pos[None, :]
@@ -217,7 +214,8 @@ def _bwd(q, k, v, o, lse, do, *, causal, window, bq, bk, interpret):
         ],
         out_specs=pl.BlockSpec((1, bq, hd), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, T, hd), q.dtype),
-        interpret=interpret,
+        compiler_params=compiler_params(),
+        interpret=interpret_mode(interpret),
     )(q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -240,7 +238,8 @@ def _bwd(q, k, v, o, lse, do, *, causal, window, bq, bk, interpret):
             jax.ShapeDtypeStruct((BH, M, hd), k.dtype),
             jax.ShapeDtypeStruct((BH, M, hd), v.dtype),
         ],
-        interpret=interpret,
+        compiler_params=compiler_params(),
+        interpret=interpret_mode(interpret),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -252,8 +251,10 @@ def _bwd(q, k, v, o, lse, do, *, causal, window, bq, bk, interpret):
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True, window: int = 0,
                     bq: int = DEFAULT_BQ, bk: int = DEFAULT_BK,
-                    interpret: bool = True) -> jax.Array:
-    """q: [B, T, H, hd]; k/v: [B, M, H, hd] (kv pre-expanded) → [B, T, H, hd]."""
+                    interpret: bool | None = None) -> jax.Array:
+    """q: [B, T, H, hd]; k/v: [B, M, H, hd] (kv pre-expanded) → [B, T, H, hd].
+
+    ``interpret=None`` follows the platform (:func:`interpret_mode`)."""
     o, _ = _flash_fwd(q, k, v, causal, window, bq, bk, interpret)
     return o
 

@@ -1,86 +1,21 @@
-"""jit'd public wrappers around the Pallas kernels.
+"""jit'd public wrappers around the Pallas kernels, and the rmsnorm/matmul
+database rows.
 
-The dispatch switch (`use_kernels`) is the kernels' Off-load Switcher: on
-TPU the Pallas modules run natively; on CPU they run in interpret mode for
-validation, and the default execution path falls back to the jnp
-references — mirroring the paper's hw-if-available / sw-fallback rule.
+The kernels always run: natively on TPU, in interpret mode on the CPU
+(:func:`repro.kernels.backend.interpret_mode`).  There is no switch back
+to the jnp references here — the module database's software rows are
+where the paper's sw-fallback placement lives.
 """
 from __future__ import annotations
 
 import functools
 
 import jax
-import jax.numpy as jnp
 
 from . import ref
-from .flash_attention import flash_attention
-from .harris import convert_scale_abs as _csa_kernel
-from .harris import corner_harris as _harris_kernel
-from .harris import cvt_color as _cvt_kernel
 from .harris import harris_fused as _harris_fused_kernel
 from .rmsnorm import rmsnorm as _rmsnorm_kernel
 from .rmsnorm import rmsnorm_matmul as _rmsnorm_matmul_kernel
-
-_USE_KERNELS = False      # CPU container default: jnp refs; TPU: flip on
-
-
-def use_kernels(on: bool = True) -> None:
-    global _USE_KERNELS
-    _USE_KERNELS = on
-
-
-def kernels_enabled() -> bool:
-    return _USE_KERNELS
-
-
-@functools.partial(jax.jit, static_argnames=("causal", "window"))
-def attention(q, k, v, causal: bool = True, window: int = 0):
-    """[B, T, H, hd] × [B, M, H, hd] (kv pre-expanded) → [B, T, H, hd]."""
-    if _USE_KERNELS:
-        return flash_attention(q, k, v, causal, window)
-    return ref.reference_attention(q, k, v, causal, window)
-
-
-@jax.jit
-def rmsnorm(x, scale):
-    shape = x.shape
-    x2 = x.reshape(-1, shape[-1])
-    if _USE_KERNELS:
-        return _rmsnorm_kernel(x2, scale).reshape(shape)
-    return ref.reference_rmsnorm(x2, scale).reshape(shape)
-
-
-@jax.jit
-def cvt_color(img):
-    if _USE_KERNELS:
-        return _cvt_kernel(img)
-    return ref.reference_cvt_color(img)
-
-
-@functools.partial(jax.jit, static_argnames=("block_size", "k"))
-def corner_harris(gray, block_size: int = 2, k: float = 0.04):
-    if _USE_KERNELS:
-        return _harris_kernel(gray, block_size, k)
-    return ref.reference_corner_harris(gray, block_size, k)
-
-
-@functools.partial(jax.jit, static_argnames=("alpha", "beta"))
-def convert_scale_abs(x, alpha: float = 1.0, beta: float = 0.0):
-    if _USE_KERNELS:
-        return _csa_kernel(x, alpha, beta)
-    return ref.reference_convert_scale_abs(x, alpha, beta)
-
-
-@jax.jit
-def rmsnorm_matmul(x, scale, w):
-    """Fused rmsnorm + matmul epilogue; x: [..., d], w: [d, out]."""
-    shape = x.shape
-    x2 = x.reshape(-1, shape[-1])
-    if _USE_KERNELS:
-        out = _rmsnorm_matmul_kernel(x2, scale, w)
-    else:
-        out = ref.reference_rmsnorm_matmul(x2, scale, w)
-    return out.reshape(*shape[:-1], w.shape[-1])
 
 
 @functools.partial(jax.jit, static_argnames=("block_size", "k", "alpha",
@@ -88,12 +23,7 @@ def rmsnorm_matmul(x, scale, w):
 def harris_response(img, block_size: int = 2, k: float = 0.04,
                     alpha: float = 1.0, beta: float = 0.0):
     """Single-call fused Harris chain (cvt → harris → csa)."""
-    if _USE_KERNELS:
-        return _harris_fused_kernel(img, block_size, k, alpha, beta,
-                                    row_block=8)
-    gray = ref.reference_cvt_color(img)
-    resp = ref.reference_corner_harris(gray, block_size, k)
-    return ref.reference_convert_scale_abs(resp, alpha, beta)
+    return _harris_fused_kernel(img, block_size, k, alpha, beta, row_block=8)
 
 
 # --------------------------------------------------------------------------- #

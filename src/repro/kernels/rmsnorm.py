@@ -7,8 +7,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .backend import compiler_params, interpret_mode
+
 ROW_BLOCK = 256
-INTERPRET = True
 
 
 def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps: float):
@@ -32,7 +33,9 @@ def rmsnorm(x: jax.Array, scale: jax.Array, eps: float = 1e-6, *,
                   pl.BlockSpec((d,), lambda i: (0,))],
         out_specs=pl.BlockSpec((rb, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N, d), x.dtype),
-        interpret=INTERPRET if interpret is None else interpret,
+        name="rmsnorm",
+        compiler_params=compiler_params(),
+        interpret=interpret_mode(interpret),
     )(x, scale)
 
 
@@ -72,5 +75,7 @@ def rmsnorm_matmul(x: jax.Array, scale: jax.Array, w: jax.Array,
                   pl.BlockSpec((d, dout), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((rb, dout), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N, dout), x.dtype),
-        interpret=INTERPRET if interpret is None else interpret,
+        name="rmsnorm_matmul",
+        compiler_params=compiler_params(),
+        interpret=interpret_mode(interpret),
     )(x, scale, w)

@@ -1,5 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 """Multi-pod dry-run — prove the distribution config is coherent (task §e).
 
 For every (architecture × input shape) cell, on the single-pod 16×16 mesh
@@ -21,6 +19,7 @@ Usage:
 import argparse
 import json
 import math
+import os
 import re
 import time
 import traceback
@@ -28,6 +27,7 @@ import traceback
 import jax
 
 from repro.configs import ARCH_IDS, SHAPES, get_config, supports_shape
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import (batch_structs, make_decode_step,
                                 make_prefill_step, make_train_step,
@@ -268,6 +268,10 @@ def _write(rec: dict, out_dir: str | None) -> None:
 
 
 def main() -> None:
+    # 512 virtual host devices for the pod meshes; must precede the first
+    # backend query (importing jax does not initialize one)
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS)
     ap.add_argument("--shape", choices=list(SHAPES))

@@ -6,16 +6,10 @@ touches jax device state (the dry-run must set XLA_FLAGS first).
 from __future__ import annotations
 
 import jax
-
-try:                                    # AxisType only exists on jax>=0.5
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 

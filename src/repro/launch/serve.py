@@ -64,6 +64,7 @@ import numpy as np
 
 from repro.configs import ARCH_IDS, get_config
 from repro.core.executor import ExecutorClosed, PipelineExecutor
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import LM
 
 # priority classes: strict priority in ascending order (0 preempts 1
@@ -1098,12 +1099,38 @@ class RequestQueueServer:
             self._finish(r, "served", dispatched=True)
 
 
+def demo_frames(n: int, size: tuple[int, int], seed: int = 0) -> list:
+    """``n`` float32 RGB frames of ``size`` (H, W) with values in [0, 255),
+    drawn on the device from ``seed``."""
+    H, W = size
+    keys = jax.random.split(jax.random.PRNGKey(seed), n)
+    return [jax.random.uniform(k, (H, W, 3), jnp.float32) * 255 for k in keys]
+
+
+@dataclass
+class PipelineDemoRun:
+    """What :func:`serve_pipeline_demo` served, and how."""
+
+    stats: dict               # RequestQueueServer.stats() of the window
+    frames: list              # the requests' frames, in submission order
+    results: list             # served outputs, in submission order
+    offloaded: Any            # the OffloadedFunction the pipeline came from
+    executor: PipelineExecutor
+    compiles_in_window: int   # executables compiled while serving
+
+
 def serve_pipeline_demo(n_requests: int = 64, max_batch: int = 8,
                         max_wait_ms: float = 4.0,
                         size: tuple[int, int] = (64, 96),
                         worker_budget: "int | str | None" = None,
-                        devices: int | None = None) -> dict:
+                        devices: int | None = None, seed: int = 0,
+                        fuse: bool = False) -> PipelineDemoRun:
     """Smoke-servable demo: Harris pipeline behind the request queue.
+
+    The app is traced unmodified, and every function the database holds a
+    Pallas module for is placed on the accelerator (``normalize`` stays in
+    software, as in the paper); ``fuse`` lets the cost model fuse
+    cvtColor+cornerHarris into one kernel.  Frames are drawn from ``seed``.
 
     ``worker_budget`` serves the pipeline with replicated stages: the
     planner's widening pass (:func:`repro.core.partition.assign_replicas`)
@@ -1124,13 +1151,11 @@ def serve_pipeline_demo(n_requests: int = 64, max_batch: int = 8,
 
     if max_batch < 1:
         raise ValueError(f"max_batch must be >= 1, got {max_batch}")
-    db = make_harris_db(with_hw=False)
+    db = make_harris_db(with_hw=True)
     lib = Library(db)
     app = corner_harris_demo(lib)
-    H, W = size
-    frames = [jax.random.uniform(jax.random.PRNGKey(i), (H, W, 3)) * 255
-              for i in range(n_requests)]
-    off = courier_offload(app, frames[0], db=db, prefer_hw=False)
+    frames = demo_frames(n_requests, size, seed)
+    off = courier_offload(app, frames[0], db=db, fuse=fuse)
     inventory = DeviceInventory.detect(limit=devices) if devices else None
     plan = off.pipeline.plan
     # the shared deploy-or-degrade rule: a plan that ends up unpinned
@@ -1150,12 +1175,15 @@ def serve_pipeline_demo(n_requests: int = 64, max_batch: int = 8,
                                inventory=inventory)
     ex.warmup(frames[0])      # compile before latencies are measured
 
+    compiles = off.pipeline.compile_count()
     with RequestQueueServer(ex, max_batch=max_batch,
                             max_wait_ms=max_wait_ms) as srv:
         reqs = [srv.submit(f) for f in frames]
-        for r in reqs:
-            r.wait(timeout=120.0)
-    return srv.stats()
+        results = [r.wait(timeout=120.0) for r in reqs]
+    return PipelineDemoRun(
+        stats=srv.stats(), frames=frames, results=results, offloaded=off,
+        executor=ex,
+        compiles_in_window=off.pipeline.compile_count() - compiles)
 
 
 def serve_traced_transformer_demo(n_requests: int = 24, max_batch: int = 4,
@@ -1245,6 +1273,7 @@ def _budget_arg(v: str):
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", choices=["lm", "pipeline", "trace"],
                     default="lm")
@@ -1288,7 +1317,7 @@ def main() -> None:
                                     max_batch=args.max_batch,
                                     max_wait_ms=args.max_wait_ms,
                                     worker_budget=args.worker_budget,
-                                    devices=args.devices)
+                                    devices=args.devices).stats
         lat = stats["latency_ms"]
         print(f"[serve] pipeline mode: {stats['requests_served']} requests, "
               f"{stats['batches']} batches "

@@ -24,6 +24,7 @@ from repro.models import LM
 from repro.optim import adamw_init
 from repro.runtime import FaultTolerantDriver, StragglerMonitor
 
+from .compile_cache import enable_compile_cache
 from .steps import make_train_step
 
 
@@ -56,6 +57,7 @@ def build(cfg, steps: int, lr: float, seq_len: int, global_batch: int):
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="gemma3-12b")
     ap.add_argument("--reduced", action="store_true")

@@ -33,7 +33,9 @@ from repro.core.database import ModuleDatabase
 def cvt_color(img: jax.Array) -> jax.Array:
     """RGB [H, W, 3] → gray [H, W] float32 (BT.601)."""
     w = jnp.asarray([0.299, 0.587, 0.114], jnp.float32)
-    return jnp.einsum("hwc,c->hw", img.astype(jnp.float32), w)
+    # HIGHEST: a TPU otherwise runs this f32 contraction in bf16 passes
+    return jnp.einsum("hwc,c->hw", img.astype(jnp.float32), w,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def corner_harris(gray: jax.Array, block_size: int = 2, k: float = 0.04) -> jax.Array:
@@ -81,6 +83,50 @@ def convert_scale_abs(x: jax.Array, alpha: float = 1.0, beta: float = 0.0) -> ja
     return jnp.clip(jnp.abs(x * alpha + beta), 0.0, 255.0)
 
 
+def numpy_reference(img, block_size: int = 2,
+                    k: float = 0.04) -> tuple[np.ndarray, np.ndarray]:
+    """Host float32 reference of the whole demo on one [H, W, 3] frame:
+    ``(Harris response, final 0-255 image)``.
+
+    Plain numpy, independent of the jnp and Pallas implementations, so a
+    device run can be held to exact f32 arithmetic.  Same border
+    convention and operation order as :func:`corner_harris`.
+    """
+    f32 = np.float32
+    x = np.asarray(img, f32)
+    gray = f32(0.299) * x[..., 0] + f32(0.587) * x[..., 1] \
+        + f32(0.114) * x[..., 2]
+    H, W = gray.shape
+    halo = 1 + block_size // 2
+    g = np.pad(gray, ((halo, halo + block_size - 1),
+                      (halo, halo + block_size - 1)), mode="edge")
+    h1, w1 = H + block_size - 1, W + block_size - 1
+
+    def sh(dy, dx):
+        return g[dy:dy + h1, dx:dx + w1]
+
+    two = f32(2)
+    dx = (sh(0, 2) + two * sh(1, 2) + sh(2, 2)
+          - sh(0, 0) - two * sh(1, 0) - sh(2, 0))
+    dy = (sh(2, 0) + two * sh(2, 1) + sh(2, 2)
+          - sh(0, 0) - two * sh(0, 1) - sh(0, 2))
+    ixx, iyy, ixy = dx * dx, dy * dy, dx * dy
+
+    def box(a):
+        out = np.zeros((H, W), f32)
+        for by in range(block_size):
+            for bx in range(block_size):
+                out = out + a[by:by + H, bx:bx + W]
+        return out
+
+    sxx, syy, sxy = box(ixx), box(iyy), box(ixy)
+    tr = sxx + syy
+    resp = (sxx * syy - sxy * sxy) - f32(k) * tr * tr
+    lo, hi = resp.min(), resp.max()
+    norm = (resp - lo) / np.maximum(hi - lo, f32(1e-12)) * f32(255)
+    return resp, np.clip(np.abs(norm), f32(0), f32(255))
+
+
 # --------------------------------------------------------------------------- #
 # the unmodified "binary" (paper Fig. 4 flow)
 # --------------------------------------------------------------------------- #
@@ -120,19 +166,12 @@ def _c_csa(shapes, dtypes, params) -> NodeCost:
     return elementwise_cost(h * w, flops_per_el=4, bytes_per_el=4, n_operands=2)
 
 
-def _fused_harris_vmem(w: int, n_parts: int, block_size: int = 2) -> int:
-    """Resident bytes of the fused row-block kernel (rb=8 slab + halos).
-
-    Mirrors ``kernels.harris.harris_fused``: an (rb + 2*halo)-row slab of
-    the padded width for the RGB load (3 planes) + the gray scratch +
-    ~6 stencil temporaries, plus one response/epilogue tile per fused part
-    beyond the first; halo grows with the box ``block_size``.
-    """
-    rb = 8
-    halo = 1 + block_size // 2
-    wp = w + 2 * halo + block_size - 1
-    bufs = 3 + 1 + 6 + (n_parts - 1)
-    return (rb + 2 * halo) * wp * 4 * bufs
+def _fused_harris_vmem(w: int, block_size: int = 2) -> int:
+    """Scoped VMEM of the fused row-block kernel at its default row block
+    (``kernels.harris.fused_vmem_bytes`` — the estimate the kernel's own
+    row-block search checks against the limit it is compiled with)."""
+    from repro.kernels import harris as hk
+    return hk.fused_vmem_bytes(hk.ROW_BLOCK, w, block_size)
 
 
 def _c_fused_pair(shapes, dtypes, params) -> NodeCost:
@@ -143,7 +182,7 @@ def _c_fused_pair(shapes, dtypes, params) -> NodeCost:
     fe = fused_cost([_c_cvt(shapes, dtypes, params),
                      _c_harris([(h, w)], dtypes, params)],
                     intermediate_bytes=4 * h * w,
-                    vmem_required=_fused_harris_vmem(w, 2, bs))
+                    vmem_required=_fused_harris_vmem(w, bs))
     return fe.cost
 
 
@@ -154,7 +193,7 @@ def _c_fused_mega(shapes, dtypes, params) -> NodeCost:
                      _c_harris([(h, w)], dtypes, params),
                      _c_csa([(h, w)], dtypes, params)],
                     intermediate_bytes=2 * (4 * h * w),   # gray + response
-                    vmem_required=_fused_harris_vmem(w, 3, bs))
+                    vmem_required=_fused_harris_vmem(w, bs))
     return fe.cost
 
 
@@ -172,22 +211,19 @@ def make_harris_db(with_hw: bool = True) -> ModuleDatabase:
     db.register("convertScaleAbs", software=convert_scale_abs, cost_hw=_c_csa,
                 cost_sw=_c_csa)
     if with_hw:
-        try:
-            from repro.kernels import harris as hk
-            db.add_accelerated("cvtColor", hk.cvt_color)
-            db.add_accelerated("cornerHarris", hk.corner_harris)
-            db.add_accelerated("convertScaleAbs", hk.convert_scale_abs)
-            # dedicated fused modules (single-pass mega-kernels): resolved
-            # by the backend for fused nodes when the cost model accepts
-            # the fusion.  In the demo chain `normalize` (sw-only) sits
-            # between cornerHarris and convertScaleAbs, so the fusable run
-            # is the pair; the 3-op mega-kernel serves normalize-free
-            # variants of the chain.
-            db.register_fused(("cvtColor", "cornerHarris"),
-                              hk.harris_fused_pair, cost_hw=_c_fused_pair)
-            db.register_fused(("cvtColor", "cornerHarris",
-                               "convertScaleAbs"),
-                              hk.harris_fused, cost_hw=_c_fused_mega)
-        except ImportError:
-            pass
+        # imported here: repro.kernels imports this module for its jnp
+        # reference implementations
+        from repro.kernels import harris as hk
+        db.add_accelerated("cvtColor", hk.cvt_color)
+        db.add_accelerated("cornerHarris", hk.corner_harris)
+        db.add_accelerated("convertScaleAbs", hk.convert_scale_abs)
+        # dedicated fused modules (single-pass mega-kernels): resolved by
+        # the backend for fused nodes when the cost model accepts the
+        # fusion.  In the demo chain `normalize` (sw-only) sits between
+        # cornerHarris and convertScaleAbs, so the fusable run is the pair;
+        # the 3-op mega-kernel serves normalize-free variants of the chain.
+        db.register_fused(("cvtColor", "cornerHarris"),
+                          hk.harris_fused_pair, cost_hw=_c_fused_pair)
+        db.register_fused(("cvtColor", "cornerHarris", "convertScaleAbs"),
+                          hk.harris_fused, cost_hw=_c_fused_mega)
     return db

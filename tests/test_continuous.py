@@ -179,8 +179,10 @@ def _drive_continuous(srv: RequestQueueServer, pool: KVSlotPool,
                       arrivals: np.ndarray, xs: np.ndarray,
                       lengths: np.ndarray) -> list:
     """Sessions of randomized length decode sequentially; the last step
-    frees the slot through ``on_finish``.  Returns per-session output
-    lists (None entries on error)."""
+    frees the slot through ``on_finish``.  An arrival that finds every
+    slot live waits for a leave, as a front end's admission would, so the
+    pool bounds concurrency however slowly the host runs.  Returns
+    per-session output lists (None entries on error)."""
     n = len(arrivals)
     outs: list = [[None] * int(lengths[i]) for i in range(n)]
     slots: list = [None] * n
@@ -206,7 +208,8 @@ def _drive_continuous(srv: RequestQueueServer, pool: KVSlotPool,
     nxt = 0
     while nxt < n or active:
         now = time.perf_counter() - t0
-        while nxt < n and arrivals[nxt] <= now:
+        while (nxt < n and arrivals[nxt] <= now
+               and pool.live_count() < pool.n_slots):
             slots[nxt] = pool.alloc()
             _submit(nxt)
             nxt += 1

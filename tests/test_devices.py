@@ -148,7 +148,7 @@ SERVE_SCRIPT = textwrap.dedent("""
 
     stats = serve_pipeline_demo(n_requests=12, max_batch=2, max_wait_ms=2.0,
                                 worker_budget="auto", devices=4,
-                                size=(48, 64))
+                                size=(48, 64)).stats
     assert stats["requests_served"] == 12, stats
     assert stats["executor"]["out_of_order_retired"] == 0
     print("SERVE-OK", stats["requests_served"])

@@ -5,6 +5,7 @@ The executor-level tests use numpy stage fns (no jit: injection + retries
 are scheduler behavior, not compilation behavior); values encode the token
 index so any seq/slot mix-up shows up as a wrong result, not just a
 counter."""
+import os
 import threading
 import time
 
@@ -346,7 +347,10 @@ def _chain_planner(times=(1.0, 4.0), inventory=None, **kw):
     return ElasticPlanner(ir, db=db, inventory=inventory, **kw)
 
 
-def test_replan_on_inventory_change_sheds_lost_device():
+def test_replan_on_inventory_change_sheds_lost_device(monkeypatch):
+    # every host core reserved: the budget is the inventory's own floor (one
+    # worker per device), so one replica per device on any host
+    monkeypatch.setenv("REPRO_RESERVED_CORES", str(os.cpu_count() or 1))
     inj = FaultInjector()
     inv = DeviceInventory.host(4)
     planner = _chain_planner(inventory=inv, fault_injector=inj,

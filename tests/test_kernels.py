@@ -116,23 +116,45 @@ def test_vmem_working_set_documented():
 
 
 def test_kernel_switch_and_fused_harris_response():
-    """The ops-layer dispatch switch: ``use_kernels`` flips what
-    ``kernels_enabled`` reports, and the single-call ``harris_response``
-    matches the three-step reference chain on the default (sw) path."""
-    from repro.kernels.ops import (harris_response, kernels_enabled,
-                                   use_kernels)
-    assert not kernels_enabled()           # CPU container default: refs
-    use_kernels(True)
-    try:
-        assert kernels_enabled()
-    finally:
-        use_kernels(False)
-    assert not kernels_enabled()
+    """The ops layer has no switch back to the jnp references: the
+    single-call ``harris_response`` runs the fused kernel (interpreted on
+    the CPU, by the platform rule) and matches the three-step reference
+    chain."""
+    from repro.kernels import ops
+    from repro.kernels.backend import interpret_mode
 
+    assert not hasattr(ops, "use_kernels")
+    assert interpret_mode() == (jax.default_backend() == "cpu")
+    assert interpret_mode(False) is False
     img = jax.random.uniform(KEY, (32, 48, 3)) * 255.0
-    got = harris_response(img)
+    got = ops.harris_response(img)
     want = ref.reference_convert_scale_abs(
         ref.reference_corner_harris(ref.reference_cvt_color(img), 2, 0.04),
         1.0, 0.0)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-4, rtol=1e-4)
+
+
+def test_every_pallas_call_compiles_under_the_planned_vmem_limit():
+    """Every kernel passes ``backend.compiler_params()``, so the scoped VMEM
+    Mosaic enforces is the ``VMEM_BYTES`` the planner checks against."""
+    import ast
+    import pathlib
+
+    import repro.kernels
+    from repro.core.costmodel import VMEM_BYTES
+    from repro.kernels.backend import compiler_params
+
+    assert compiler_params().vmem_limit_bytes == VMEM_BYTES
+    calls = []
+    for path in sorted(pathlib.Path(repro.kernels.__file__).parent.glob(
+            "*.py")):
+        for node in ast.walk(ast.parse(path.read_text())):
+            if (isinstance(node, ast.Call)
+                    and isinstance(node.func, ast.Attribute)
+                    and node.func.attr == "pallas_call"):
+                kw = {k.arg: ast.unparse(k.value) for k in node.keywords}
+                calls.append((path.name, node.lineno,
+                              kw.get("compiler_params")))
+    assert len(calls) == 9, calls
+    assert all(cp == "compiler_params()" for _, _, cp in calls), calls

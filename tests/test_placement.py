@@ -110,6 +110,19 @@ def test_device_inventory_synthetic_and_validation():
         DeviceSpec(ordinal=0, speed=0.0)
 
 
+def test_synthetic_tpu_inventory_is_costed_as_its_kind_never_as_cpu():
+    tpu = DeviceInventory.host(2, "TPU v5 lite")
+    assert tpu.spec(1).platform == "tpu" and tpu.spec(1).kind == "TPU v5 lite"
+    assert tpu.device_class(1) is device_class("TPU v5 lite")
+    assert tpu.device_class(0) is not device_class("cpu")
+    with pytest.raises(ValueError, match="does not match"):
+        DeviceSpec(ordinal=0, platform="tpu")     # left with the cpu kind
+    with pytest.raises(ValueError, match="does not match"):
+        DeviceSpec(ordinal=0, platform="cpu", kind="TPU v5 lite")
+    with pytest.raises(ValueError, match="no platform"):
+        DeviceInventory.host(2, "tpu")            # a platform is not a kind
+
+
 def test_device_inventory_detect_matches_jax_devices():
     import jax
 
@@ -117,6 +130,8 @@ def test_device_inventory_detect_matches_jax_devices():
     assert len(inv) == len(jax.devices())
     assert inv.jax_device(0) is jax.devices()[0]
     assert inv.spec(0).platform == jax.devices()[0].platform
+    assert inv.spec(0).kind == jax.devices()[0].device_kind
+    assert inv.device_class(0).name == jax.devices()[0].device_kind
     with pytest.raises(ValueError, match="limit"):
         DeviceInventory.detect(limit=0)
 
@@ -417,11 +432,14 @@ def test_per_device_class_roofline_costing():
     from repro.core import NodeCost
 
     c = NodeCost(flops=1e9, bytes_rw=1e6)
-    t_tpu = c.time_ms(device=device_class("tpu"))
+    t_tpu = c.time_ms(device=device_class("TPU v5 lite"))
     t_cpu = c.time_ms(device=device_class("cpu"))
     assert t_cpu > t_tpu                      # same op, slower device class
-    assert c.time_ms() == pytest.approx(t_tpu)   # default = TPU table
-    assert device_class("nonsense") is device_class("tpu")
+    assert c.time_ms() == pytest.approx(t_tpu)   # default = TPU v5e table
+    with pytest.raises(ValueError, match="no roofline constants"):
+        device_class("nonsense")              # unknown kind: error, no default
+    with pytest.raises(ValueError):
+        device_class("tpu")                   # a platform is not a kind
     # measured times win regardless of device class
     m = NodeCost(flops=1e9, bytes_rw=1e6, measured_ms=7.0)
     assert m.time_ms(device=device_class("cpu")) == 7.0

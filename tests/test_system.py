@@ -7,7 +7,9 @@ import pytest
 from repro.core import (CourierIR, Frontend, Library, ModuleDatabase,
                         OffloadPlan, PipelineGenerator, courier_offload,
                         deploy, linear_ir, partition_paper)
-from repro.models.harris import corner_harris_demo, make_harris_db
+from repro.launch.serve import serve_pipeline_demo
+from repro.models.harris import (corner_harris_demo, make_harris_db,
+                                 numpy_reference)
 
 
 def _demo_db():
@@ -152,3 +154,73 @@ def test_harris_app_with_hw_kernels():
     scale = float(jnp.max(jnp.abs(ref)))
     np.testing.assert_allclose(np.asarray(got) / scale,
                                np.asarray(ref) / scale, atol=1e-4)
+
+
+@pytest.mark.parametrize("fuse", [False, True], ids=["unfused", "fused"])
+def test_smoke_path_serves_hardware_pipeline_at_reference_parity(fuse):
+    """The path chip_smoke.py drives on the chip, at a 64x96 frame with
+    interpreted kernels: the Pallas modules are placed (the fused pair under
+    ``fuse``), nothing falls back or compiles while serving, and every
+    served frame, and the Harris response the served stage programs give
+    it, match the host numpy float32 reference within the script's
+    tolerances."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chip_smoke.py")
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smoke)
+    run = serve_pipeline_demo(n_requests=6, max_batch=4, size=(64, 96),
+                              seed=3, fuse=fuse)
+    nodes = run.offloaded.pipeline.ir.nodes
+    placements = {n.fn_key: n.placement.kind for n in nodes}
+    head = ({"cvtColor+cornerHarris": "hw"} if fuse
+            else {"cvtColor": "hw", "cornerHarris": "hw"})
+    assert placements == {**head, "normalize": "sw", "convertScaleAbs": "hw"}
+    assert [n.fn_key for n in nodes if n.fused_from] == (
+        ["cvtColor+cornerHarris"] if fuse else [])
+    assert run.offloaded.plan.fallback_log == []
+    assert run.offloaded.fallbacks == []
+    assert run.compiles_in_window == 0
+    assert run.stats["requests_served"] == len(run.results) == 6
+    responses = smoke.served_responses(
+        run, "cvtColor+cornerHarris" if fuse else "cornerHarris")
+    assert len(responses) == 6
+    for frame, got, resp in zip(run.frames, run.results, responses):
+        ref_resp, want = numpy_reference(np.asarray(frame))
+        np.testing.assert_allclose(np.asarray(got), want, rtol=0,
+                                   atol=smoke.IMAGE_ATOL)
+        np.testing.assert_allclose(
+            np.asarray(resp), ref_resp, rtol=0,
+            atol=smoke.RESPONSE_RTOL * float(np.max(np.abs(ref_resp))))
+
+
+@pytest.mark.parametrize("from_env", [True, False], ids=["env", "default"])
+def test_compile_cache_placed_from_env_or_fixed_checkout_path(from_env,
+                                                             tmp_path):
+    """``enable_compile_cache`` leaves a ``JAX_COMPILATION_CACHE_DIR`` set
+    from outside alone, and otherwise points JAX at the one fixed
+    directory in the checkout (run in a child: it sets global config)."""
+    import os
+    import subprocess
+    import sys
+
+    from repro.launch.compile_cache import CACHE_DIR
+
+    env = dict(os.environ)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "src"), env.get("PYTHONPATH", "")])
+    code = ("import jax; from repro.launch.compile_cache import "
+            "enable_compile_cache as e; d = e(); "
+            "print(d); print(jax.config.jax_compilation_cache_dir)")
+    out = subprocess.run([sys.executable, "-c", code], env=env, check=True,
+                         capture_output=True, text=True, timeout=120)
+    want = str(tmp_path) if from_env else CACHE_DIR
+    assert out.stdout.split() == [want, want]
+    assert os.path.basename(CACHE_DIR) == ".jax_cache"
