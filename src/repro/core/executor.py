@@ -25,9 +25,17 @@ pool:
   through ``jax.vmap``-ed stage functions as one group, amortizing dispatch
   overhead (``microbatch=m``).  Results are unstacked at retirement, so the
   API is token-in/token-out either way.
-* **Counters** — per-stage issue counts/host-issue time and pool occupancy
-  are tracked continuously; :meth:`PipelineExecutor.stats` exposes
-  throughput and occupancy for the serving layer's metrics endpoint.
+* **Counters and spans** — per-stage issue counts and host-issue time,
+  pool occupancy, the host time admission waited on a full pool
+  (``pool_wait_ms``), the time retirement spent unstacking groups
+  (``unstack_ms``), and the group rows dispatched and padded are tracked
+  continuously; :meth:`PipelineExecutor.stats` exposes them for the
+  serving layer's metrics endpoint.  Admission and retirement are also
+  ``jax.profiler.TraceAnnotation`` spans (``dispatch`` with children
+  ``dispatch.stack``, ``dispatch.pool_wait`` and ``dispatch.issue``;
+  ``retire`` with ``retire.wait`` and ``retire.unstack``), tagged with a
+  group id unique over the executor's life.  They cost about a
+  microsecond each and are written only while a profile is recording.
 * **Online profiling** — an attached
   :class:`~repro.core.profiler.StageProfiler` is fed measured per-stage
   wall times: exactly in threaded mode, by sampled blocking barriers in
@@ -82,6 +90,7 @@ Completion is in-order (tokens retire oldest-first), matching the paper's
 """
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from collections import deque
@@ -92,6 +101,9 @@ from typing import Any, Callable, Iterable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
+
+from .pipeline import batched_body
 
 __all__ = ["PipelineExecutor", "ExecutorStats", "StageCounters",
            "PendingToken", "SubmitError", "ExecutorClosed"]
@@ -134,9 +146,6 @@ class StageCounters:
     tokens: int = 0        # tokens pushed through this stage
     errors: int = 0        # stage-call failures (pre-retry; see retries)
     issue_ms: float = 0.0  # host time spent dispatching this stage
-    # measured stage-body wall time (threaded/sampled only); disjoint from
-    # xfer_ms — exec_ms + xfer_ms is the stage's full service time
-    exec_ms: float = 0.0
     xfer_ms: float = 0.0   # host time staging groups onto pinned devices
     replicas: int = 1      # worker threads serving this stage
     # CONFIGURED per-replica device ordinals (empty = unpinned).  This
@@ -152,7 +161,6 @@ class StageCounters:
         return {"issued": self.issued, "tokens": self.tokens,
                 "errors": self.errors,
                 "issue_ms": round(self.issue_ms, 4),
-                "exec_ms": round(self.exec_ms, 4),
                 "xfer_ms": round(self.xfer_ms, 4),
                 "replicas": self.replicas,
                 "devices": list(self.devices),
@@ -177,6 +185,10 @@ class ExecutorStats:
     quarantined: int = 0           # replicas evicted after repeated errors
     seam_joins: int = 0            # tokens admitted into in-flight groups
     seam_evictions: int = 0        # seats evicted before their group sealed
+    pool_wait_ms: float = 0.0      # admission blocked on a full token pool
+    unstack_ms: float = 0.0        # retirement slicing groups into tokens
+    rows_dispatched: int = 0       # group rows sent to the stages, padding in
+    rows_padded: int = 0           # of those, rows that carry no token
     # failed stage calls per CONFIGURED device ordinal — the replanner's
     # unhealthy-device signal (populated only for device-placed replicas)
     device_errors: dict = field(default_factory=dict)
@@ -187,13 +199,6 @@ class ExecutorStats:
         if not self.occupancy_samples:
             return 0.0
         return self.occupancy_sum / self.occupancy_samples
-
-    @property
-    def throughput_tps(self) -> float:
-        """Retired tokens per second over the accumulated ``run`` wall time."""
-        if self.wall_ms <= 0:
-            return 0.0
-        return self.tokens_retired / (self.wall_ms / 1e3)
 
     def as_dict(self) -> dict:
         return {
@@ -207,13 +212,16 @@ class ExecutorStats:
             "quarantined": self.quarantined,
             "seam_joins": self.seam_joins,
             "seam_evictions": self.seam_evictions,
+            "pool_wait_ms": round(self.pool_wait_ms, 4),
+            "unstack_ms": round(self.unstack_ms, 4),
+            "rows_dispatched": self.rows_dispatched,
+            "rows_padded": self.rows_padded,
             "device_errors": {str(k): v
                               for k, v in sorted(self.device_errors.items())},
             "quarantined_replicas": [list(t)
                                      for t in self.quarantined_replicas],
             "mean_occupancy": round(self.mean_occupancy, 3),
             "wall_ms": round(self.wall_ms, 3),
-            "throughput_tps": round(self.throughput_tps, 2),
             "per_stage": [s.as_dict() for s in self.per_stage],
         }
 
@@ -265,10 +273,12 @@ class _Group:
 
     __slots__ = ("env", "size", "stacked", "results", "done", "error", "lock",
                  "future", "seq", "fns", "evt", "retries", "t_admit",
-                 "sealed", "rows", "sig", "evicted")
+                 "sealed", "rows", "sig", "evicted", "gid")
 
-    def __init__(self, env: dict | None, size: int, stacked: bool):
+    def __init__(self, env: dict | None, size: int, stacked: bool,
+                 gid: int):
         self.env = env                # None until all stages are issued
+        self.gid = gid                # group id: joins its spans in a profile
         self.size = size              # real tokens (padding rows excluded)
         self.stacked = stacked
         self.results: list[Any] | None = None
@@ -630,6 +640,7 @@ class PipelineExecutor:
         self._inflight: deque[_Group] = deque()
         self._occupancy = 0               # live (non-retired) tokens
         self._lock = threading.RLock()
+        self._group_ids = itertools.count()   # span ids, never reset
         self.closed = False
         self._seq = 0                     # admission sequence (replicated)
         self._next_retire_seq = 0         # in-order retirement watermark
@@ -725,19 +736,20 @@ class PipelineExecutor:
                     f"token {i}: expected {len(self.graph_inputs)} inputs, "
                     f"got {len(t)}")
         handles: list[PendingToken] = []
-        for group_toks in self._group_tokens(toks):
-            try:
-                handles.extend(self._admit(group_toks))
-            except ExecutorClosed:
-                if not handles:
-                    raise           # nothing issued: the clean "closed" case
-                raise SubmitError(
-                    f"executor closed after token {len(handles)}",
-                    handles) from None
-            except BaseException as e:
-                raise SubmitError(
-                    f"submit failed at token {len(handles)}: {e}",
-                    handles) from e
+        with TraceAnnotation("dispatch"):
+            for group_toks in self._group_tokens(toks):
+                try:
+                    handles.extend(self._admit(group_toks))
+                except ExecutorClosed:
+                    if not handles:
+                        raise       # nothing issued: the clean "closed" case
+                    raise SubmitError(
+                        f"executor closed after token {len(handles)}",
+                        handles) from None
+                except BaseException as e:
+                    raise SubmitError(
+                        f"submit failed at token {len(handles)}: {e}",
+                        handles) from e
         return handles
 
     # -- continuous batching (open_groups mode) ------------------------------ #
@@ -783,6 +795,7 @@ class PipelineExecutor:
                 self._occupancy += 1
                 self._stats.tokens_admitted += 1
                 self._stats.seam_joins += 1
+                self._stats.rows_padded -= 1     # the seat now holds a token
                 self._stats.max_in_flight_seen = max(
                     self._stats.max_in_flight_seen, self._occupancy)
                 self._stats.occupancy_samples += 1
@@ -966,11 +979,11 @@ class PipelineExecutor:
         if size == 1:
             return self.stage_fns
         if self._batched_fns is None:
-            # vmap over the env dict (a pytree of per-token arrays) — over
-            # the *raw* stage body when the stage is a StageFn, so one
-            # jit(vmap(...)) owns the executable cache; jit so repeated
-            # group sizes reuse the compiled executable.
-            self._batched_fns = [jax.jit(jax.vmap(getattr(f, "raw", f)))
+            # vmap over the env dict (a pytree of per-token arrays) — the
+            # stage's own batched body when it is a StageFn, so one
+            # jit(...) owns the executable cache; jit so repeated group
+            # sizes reuse the compiled executable.
+            self._batched_fns = [jax.jit(batched_body(f))
                                  for f in self.stage_fns]
         return self._batched_fns
 
@@ -1009,6 +1022,8 @@ class PipelineExecutor:
         size = len(group_toks)
         pad = self._pad_for(size)
         stacked = size > 1 or pad > 0
+        gid = next(self._group_ids)
+        n_rows = size + pad
         if stacked:
             # padding rows: a neutral pad_token when one is configured
             # (dead rows a stateful stage must not mutate — and the seats
@@ -1018,7 +1033,8 @@ class PipelineExecutor:
             filler = (self.pad_token if self.pad_token is not None
                       else group_toks[-1])
             rows = group_toks + [filler] * pad
-            args = tuple(jnp.stack(c) for c in zip(*rows))
+            with TraceAnnotation("dispatch.stack", group=gid, rows=n_rows):
+                args = tuple(jnp.stack(c) for c in zip(*rows))
         else:
             args = group_toks[0]
         env = self._env_of(args)
@@ -1027,11 +1043,12 @@ class PipelineExecutor:
         #    its per-group lock held, so finalizers queue on g.lock until
         #    issue completes — the executor lock itself is only held for
         #    O(us) bookkeeping, never across a jit trace/compile.
-        g = _Group(None, size, stacked)
-        g.rows = size + pad if stacked else size
+        g = _Group(None, size, stacked, gid)
+        g.rows = n_rows
         if self.open_groups:
             g.sig = _sig_of(group_toks[0])
         g.lock.acquire()
+        waited_ms = 0.0
         while True:
             with self._lock:
                 if self.closed:
@@ -1050,6 +1067,9 @@ class PipelineExecutor:
                     self._occupancy += size
                     self._stats.tokens_admitted += size
                     self._stats.groups_admitted += 1
+                    self._stats.rows_dispatched += n_rows
+                    self._stats.rows_padded += pad
+                    self._stats.pool_wait_ms += waited_ms
                     self._stats.max_in_flight_seen = max(
                         self._stats.max_in_flight_seen, self._occupancy)
                     self._stats.occupancy_samples += 1
@@ -1059,54 +1079,17 @@ class PipelineExecutor:
             # backpressure: pool full — retire the oldest group.  The device
             # wait happens OUTSIDE self._lock so concurrent retirers
             # (serving threads) never stall admission behind it.
-            self._finalize(oldest)
+            t0 = time.perf_counter()
+            with TraceAnnotation("dispatch.pool_wait", group=gid,
+                                 rows=n_rows):
+                self._finalize(oldest)
+            waited_ms += (time.perf_counter() - t0) * 1e3
 
         # 2) issue every stage outside the executor lock (the first call of
         #    a new group size pays the vmap+jit trace here)
         try:
-            fns = self._stage_fns_for(size + pad if stacked else 1)
-            counters = []
-            if self._rings is not None:
-                t0 = time.perf_counter()
-                g.env = env
-                g.fns = tuple(fns)
-                g.evt = threading.Event()
-                if self.open_groups and g.rows > g.size:
-                    # publish the group as OPEN before routing: joins may
-                    # claim its padding seats until the stage-0 worker
-                    # seals it (both transitions under self._lock)
-                    with self._lock:
-                        g.sealed = False
-                        self._open.append(g)
-                self._route(0, g.seq, g)
-                enq = (time.perf_counter() - t0) * 1e3 / max(len(fns), 1)
-                counters = [(si, enq) for si in range(len(fns))]
-            elif self._pools is not None:
-                t0 = time.perf_counter()
-                self._issue_threaded(g, env, fns)
-                enq = (time.perf_counter() - t0) * 1e3 / max(len(fns), 1)
-                counters = [(si, enq) for si in range(len(fns))]
-            else:
-                # async-dispatch issue; sampled groups pay a blocking
-                # barrier per stage so the profiler sees real wall times
-                sample = self.profiler is not None and self.profiler.tick()
-                for si, fn in enumerate(fns):
-                    if self._injector is not None:
-                        # unreplicated path: injected faults error the
-                        # group at issue time (no replica to retry on)
-                        self._injector.on_stage_call(si)
-                    t0 = time.perf_counter()
-                    env = fn(env)   # returns immediately (async dispatch)
-                    # issue_ms stays a pure dispatch metric: capture it
-                    # before any profiling barrier
-                    counters.append((si, (time.perf_counter() - t0) * 1e3))
-                    if sample:
-                        env = jax.block_until_ready(env)
-                        ms = (time.perf_counter() - t0) * 1e3
-                        self.profiler.record(si, ms)
-                        with self._lock:
-                            self._stats.per_stage[si].exec_ms += ms
-                g.env = env
+            with TraceAnnotation("dispatch.issue", group=gid, rows=n_rows):
+                counters = self._issue(g, env)
         except BaseException as e:
             # unwind the reservation so the failed group neither blocks the
             # pool nor surfaces bogus results
@@ -1119,6 +1102,8 @@ class PipelineExecutor:
                 self._occupancy -= g.size
                 self._stats.tokens_admitted -= g.size
                 self._stats.groups_admitted -= 1
+                self._stats.rows_dispatched -= g.rows
+                self._stats.rows_padded -= g.rows - g.size
                 try:
                     self._inflight.remove(g)
                 except ValueError:
@@ -1144,6 +1129,50 @@ class PipelineExecutor:
                 c.tokens += size
                 c.issue_ms += ms
         return [PendingToken(self, g, i) for i in range(size)]
+
+    def _issue(self, g: _Group, env: dict) -> list[tuple[int, float]]:
+        """Issue every stage of group ``g``; returns ``(stage, host ms)``
+        pairs for ``issue_ms``."""
+        fns = self._stage_fns_for(g.rows)
+        if self._rings is not None:
+            t0 = time.perf_counter()
+            g.env = env
+            g.fns = tuple(fns)
+            g.evt = threading.Event()
+            if self.open_groups and g.rows > g.size:
+                # publish the group as OPEN before routing: joins may
+                # claim its padding seats until the stage-0 worker
+                # seals it (both transitions under self._lock)
+                with self._lock:
+                    g.sealed = False
+                    self._open.append(g)
+            self._route(0, g.seq, g)
+            enq = (time.perf_counter() - t0) * 1e3 / max(len(fns), 1)
+            return [(si, enq) for si in range(len(fns))]
+        if self._pools is not None:
+            t0 = time.perf_counter()
+            self._issue_threaded(g, env, fns)
+            enq = (time.perf_counter() - t0) * 1e3 / max(len(fns), 1)
+            return [(si, enq) for si in range(len(fns))]
+        # async-dispatch issue; sampled groups pay a blocking barrier per
+        # stage so the profiler sees real wall times
+        counters = []
+        sample = self.profiler is not None and self.profiler.tick()
+        for si, fn in enumerate(fns):
+            if self._injector is not None:
+                # unreplicated path: injected faults error the group at
+                # issue time (no replica to retry on)
+                self._injector.on_stage_call(si)
+            t0 = time.perf_counter()
+            env = fn(env)   # returns immediately (async dispatch)
+            # issue_ms stays a pure dispatch metric: capture it before any
+            # profiling barrier
+            counters.append((si, (time.perf_counter() - t0) * 1e3))
+            if sample:
+                env = jax.block_until_ready(env)
+                self.profiler.record(si, (time.perf_counter() - t0) * 1e3)
+        g.env = env
+        return counters
 
     # -- replicated-stage dataflow (sequence-numbered rings) ----------------- #
     def _route(self, si: int, seq: int, g: _Group) -> None:
@@ -1265,10 +1294,7 @@ class PipelineExecutor:
                     self.profiler.record(si, ms, replica=w,
                                          device=ordinal)
                 with self._lock:
-                    # counters are DISJOINT: exec_ms is the stage body
-                    # alone, xfer_ms the staging hop (sum = service)
                     c = self._stats.per_stage[si]
-                    c.exec_ms += ms - xfer
                     c.xfer_ms += xfer
                     for d in ran_on:
                         c.ran_on[d] = c.ran_on.get(d, 0) + 1
@@ -1382,11 +1408,8 @@ class PipelineExecutor:
             self._injector.on_stage_call(si)
         t0 = time.perf_counter()
         out = jax.block_until_ready(fn(env))
-        ms = (time.perf_counter() - t0) * 1e3
         if self.profiler is not None:
-            self.profiler.record(si, ms)
-        with self._lock:
-            self._stats.per_stage[si].exec_ms += ms
+            self.profiler.record(si, (time.perf_counter() - t0) * 1e3)
         return out
 
     def _retire_through(self, group: _Group) -> None:
@@ -1404,28 +1427,36 @@ class PipelineExecutor:
         Idempotent; callable from any thread.  The executor lock is NOT
         held across the device wait — only the per-group lock serializes
         double-finalization, so admission can proceed while a serving
-        thread blocks here.
+        thread blocks here.  The ``retire`` span's time outside its
+        ``retire.wait`` and ``retire.unstack`` children is the wait for
+        ``g.lock``: contention between retirers.
         """
         finalized_here = False
-        with g.lock:
+        unstack_ms = 0.0
+        with TraceAnnotation("retire", group=g.gid, rows=g.rows), g.lock:
             if not g.done:
                 try:
-                    if g.evt is not None:         # replicated stage workers
-                        g.evt.wait()
-                        if g.error is not None:
-                            raise g.error
-                    elif g.future is not None:    # threaded stage workers
-                        g.env = g.future.result()
-                    out = self._out_of(g.env)
-                    jax.block_until_ready(out)
-                    if g.stacked:
-                        if isinstance(out, tuple):
+                    with TraceAnnotation("retire.wait", group=g.gid,
+                                         rows=g.rows):
+                        if g.evt is not None:     # replicated stage workers
+                            g.evt.wait()
+                            if g.error is not None:
+                                raise g.error
+                        elif g.future is not None:  # threaded stage workers
+                            g.env = g.future.result()
+                        out = self._out_of(g.env)
+                        jax.block_until_ready(out)
+                    t0 = time.perf_counter()
+                    with TraceAnnotation("retire.unstack", group=g.gid,
+                                         rows=g.rows):
+                        if not g.stacked:
+                            g.results = [out]
+                        elif isinstance(out, tuple):
                             g.results = [tuple(o[i] for o in out)
                                          for i in range(g.size)]
                         else:
                             g.results = [out[i] for i in range(g.size)]
-                    else:
-                        g.results = [out]
+                    unstack_ms = (time.perf_counter() - t0) * 1e3
                 except BaseException as e:
                     # an execute-time failure (threaded stage, or a runtime
                     # error surfacing at the blocking wait): the group still
@@ -1438,6 +1469,7 @@ class PipelineExecutor:
         with self._lock:
             if finalized_here:           # exactly-once accounting per group
                 self._stats.tokens_retired += g.size
+                self._stats.unstack_ms += unstack_ms
                 if g.error is not None:
                     self._stats.tokens_failed += g.size
                 self._occupancy -= g.size

@@ -27,11 +27,12 @@ Two token-stream execution paths are exposed:
   paper-faithful baseline.
 * ``BuiltPipeline.run_async`` / ``BuiltPipeline.executor()`` — the true
   asynchronous executor (:mod:`repro.core.executor`): eager stage issue,
-  bounded token pool, optional per-stage micro-batching, throughput and
+  bounded token pool, optional per-stage micro-batching, issue, wait and
   occupancy counters.  This is the serving-layer fast path.
 """
 from __future__ import annotations
 
+import re
 import warnings
 from collections import deque
 from dataclasses import dataclass, field
@@ -48,7 +49,8 @@ from .partition import (PipelinePlan, StagePlan, fuse_adjacent_hw,
 from .placement import HW, SW, Placement, is_hw
 
 __all__ = ["PipelineGenerator", "BuiltPipeline", "StageFn",
-           "assign_placements", "make_stage_fns", "loop_batched"]
+           "assign_placements", "make_stage_fns", "loop_batched",
+           "batched_body", "stage_name"]
 
 
 def loop_batched(fn: Callable) -> Callable:
@@ -236,6 +238,77 @@ def _resolve_impl(node: Node, ir: CourierIR, db: ModuleDatabase) -> Callable:
     return fn
 
 
+def stage_name(nodes: Sequence[Node]) -> str:
+    """``stage_<call>_<call>...``: a stage program named after the library
+    calls it holds (``stage_cvtColor_cornerHarris``), so a profile's
+    ``XLA Modules`` line tells the stages apart.  No stage index: a stage
+    reused across re-plans keeps its name."""
+    return "stage_" + re.sub(r"[^A-Za-z0-9_]", "_",
+                             "_".join(n.fn_key for n in nodes))
+
+
+def _stage_body(nodes: Sequence[Node], impls: Sequence[Callable],
+                live_out: Sequence[str], captured: dict, *,
+                vmapped: bool) -> Callable:
+    """The Python body of one stage, each library call under
+    ``jax.named_scope(fn_key)``.
+
+    ``vmapped`` makes the micro-batched body: every call is vmapped over
+    the group's leading axis INSIDE its scope.  Vmapping the whole body
+    would put the scope under ``vmap(...)`` in the name stack, and the TPU
+    compiler names a Pallas custom call after the name-stack entry that
+    holds it, so ``vmap_cvt_color_`` would turn into ``cvt_color``; per
+    call, the compiled program and its op names match a whole-body vmap.
+    """
+    nodes, impls, live_out = tuple(nodes), tuple(impls), tuple(live_out)
+
+    def call(node: Node, impl: Callable, ins: dict) -> tuple:
+        # captured operands come from the closure (pipeline-held
+        # constants), everything else from the live env; keyword-bound
+        # arrays (input_kw) replay under their trace-time name
+        kws = node.input_kw or [None] * len(node.inputs)
+        pos = [ins[v] if v in ins else captured[v]
+               for v, kw in zip(node.inputs, kws) if kw is None]
+        kw = {kw: ins[v] if v in ins else captured[v]
+              for v, kw in zip(node.inputs, kws) if kw is not None}
+        out = impl(*pos, **kw, **node.params)
+        return out if isinstance(out, (tuple, list)) else (out,)
+
+    def stage(env: dict) -> dict:
+        env = dict(env)
+        rows = (jax.tree.leaves(env)[0].shape[0]
+                if vmapped and env else None)
+        for node, impl in zip(nodes, impls):
+            ins = {v: env[v] for v in node.inputs if v in env}
+            with jax.named_scope(node.fn_key):
+                if vmapped:
+                    outs = jax.vmap(lambda a, n=node, f=impl: call(n, f, a),
+                                    axis_size=rows)(ins)
+                else:
+                    outs = call(node, impl, ins)
+            env.update(zip(node.outputs, outs))
+        out = {}
+        for k in live_out:
+            if k in env:
+                out[k] = env[k]
+            elif vmapped:        # a captured graph output, one per row
+                c = captured[k]
+                out[k] = jnp.broadcast_to(c, (rows,) + jnp.shape(c))
+            else:
+                out[k] = captured[k]
+        return out
+
+    stage.__name__ = stage_name(nodes)
+    return stage
+
+
+def batched_body(f: Callable) -> Callable:
+    """The micro-batched form of stage ``f`` (a group of tokens stacked on a
+    leading axis): a :class:`StageFn`'s per-call vmapped body, else
+    ``jax.vmap`` of its raw body."""
+    return getattr(f, "vmapped", None) or jax.vmap(getattr(f, "raw", f))
+
+
 class StageFn:
     """One compiled pipeline stage: ``dict(live-in) -> dict(live-out)``.
 
@@ -243,8 +316,9 @@ class StageFn:
     the pipeline's lifetime, so steady-state serving re-enters the same
     executable instead of re-tracing — and exposes the XLA compile count
     (``jit``'s signature-cache size) so callers can assert **zero recompiles
-    after warmup**.  ``raw`` is kept for transform composition (the executor
-    vmaps it for micro-batching).
+    after warmup**.  ``raw`` is kept for transform composition (stateful
+    stages loop it per row); ``vmapped`` is the body micro-batched groups
+    run (see :func:`batched_body`).
 
     ``donate`` forwards the env argument's buffers to XLA as donated inputs:
     stage outputs may reuse stage-input memory, killing the per-token
@@ -253,11 +327,13 @@ class StageFn:
     inputs — the generator checks liveness before enabling it).
     """
 
-    __slots__ = ("raw", "jitted", "donated", "stateful", "_fn", "__name__")
+    __slots__ = ("raw", "vmapped", "jitted", "donated", "stateful", "_fn",
+                 "__name__")
 
     def __init__(self, fn: Callable, *, jit: bool = True,
-                 donate: bool = False):
+                 donate: bool = False, vmapped: Callable | None = None):
         self.raw = fn
+        self.vmapped = vmapped
         self.jitted = jit
         self.donated = donate and jit
         # stage contains a stateful (slot-pool-mutating) node: never jit
@@ -330,26 +406,11 @@ def make_stage_fns(ir: CourierIR, db: ModuleDatabase, plan: PipelinePlan,
             continue
         impls = [_resolve_impl(n, ir, db) for n in nodes]
         captured = dict(getattr(ir, "captured", {}))
-
-        def stage(env: dict, _nodes=tuple(nodes), _impls=tuple(impls),
-                  _live=tuple(live_out), _cap=captured):
-            env = dict(env)
-            for node, impl in zip(_nodes, _impls):
-                # captured operands come from the closure (pipeline-held
-                # constants), everything else from the live env; keyword-
-                # bound arrays (input_kw) replay under their trace-time name
-                kws = node.input_kw or [None] * len(node.inputs)
-                pos = [env[v] if v in env else _cap[v]
-                       for v, kw in zip(node.inputs, kws) if kw is None]
-                kw = {kw: env[v] if v in env else _cap[v]
-                      for v, kw in zip(node.inputs, kws) if kw is not None}
-                out = impl(*pos, **kw, **node.params)
-                outs = out if isinstance(out, (tuple, list)) else (out,)
-                for name, o in zip(node.outputs, outs):
-                    env[name] = o
-            return {k2: env[k2] if k2 in env else _cap[k2] for k2 in _live}
-
-        sf = StageFn(stage, jit=stage_jit, donate=can_donate)
+        sf = StageFn(
+            _stage_body(nodes, impls, live_out, captured, vmapped=False),
+            jit=stage_jit, donate=can_donate,
+            vmapped=_stage_body(nodes, impls, live_out, captured,
+                                vmapped=True))
         sf.stateful = has_state
         if cache is not None:
             cache[key] = sf
@@ -493,7 +554,7 @@ class BuiltPipeline:
             self._batched_fns = [
                 loop_batched(getattr(f, "raw", f))
                 if getattr(f, "stateful", False)
-                else jax.jit(jax.vmap(getattr(f, "raw", f)))
+                else jax.jit(batched_body(f))
                 for f in self.stage_fns]
         return self._batched_fns
 

@@ -24,7 +24,10 @@ Two serving modes:
   cannot absorb is *shed* (fast-failed with :class:`Overloaded`) instead
   of blocking submitters, and a degradation ladder sheds best-effort
   traffic first.  Per-request latency (queue + execute) is recorded and
-  summarized per class by :meth:`RequestQueueServer.stats`.
+  summarized per class by :meth:`RequestQueueServer.stats`.  The batcher's
+  wait for a batch is a ``batcher_wait`` profiler span, beside the
+  executor's ``dispatch`` and ``retire`` spans (README "Tracing a served
+  pipeline").
 
 Overload-protection model (see EXPERIMENTS.md "Overload protection"):
 
@@ -61,6 +64,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs import ARCH_IDS, get_config
 from repro.core.executor import ExecutorClosed, PipelineExecutor
@@ -997,7 +1001,8 @@ class RequestQueueServer:
     def _batch_loop(self) -> None:
         while self._running or not self._queues.empty():
             self._maybe_swap()            # executor swaps at batch boundaries
-            batch = self._collect_batch()
+            with TraceAnnotation("batcher_wait"):
+                batch = self._collect_batch()
             if not batch:
                 continue
             self._refresh_admission_period()
