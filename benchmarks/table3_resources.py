@@ -8,7 +8,7 @@ from __future__ import annotations
 
 from repro.configs.harris import config as HARRIS
 from repro.core.costmodel import LANE, MXU_TILE, SUBLANE, VMEM_BYTES
-from repro.kernels.harris import ROW_BLOCK
+from repro.kernels.harris import ROW_BLOCK, cvt_row_block
 
 
 def _row(name: str, vmem_bytes: int, grid: int, note: str):
@@ -20,8 +20,9 @@ def run() -> list[tuple[str, float, str]]:
     H, W = HARRIS.height, HARRIS.width
     rb = ROW_BLOCK
     rows = []
-    # cvtColor: in block [rb, W, 3] u8→f32 + out [rb, W] f32
-    rows.append(_row("cvtColor", rb * W * 3 * 4 + rb * W * 4, H // rb,
+    # cvtColor: in block [3, crb, W] f32 (three colour planes) + out [crb, W]
+    crb = cvt_row_block(H, W)
+    rows.append(_row("cvtColor", crb * W * 3 * 4 + crb * W * 4, H // crb,
                      f"VPU elementwise, {W}-lane rows"))
     # cornerHarris: halo rows + 3 sobel products + 3 sums + out (f32)
     halo = 2

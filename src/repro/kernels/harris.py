@@ -14,6 +14,14 @@ TPU adaptation of the paper's streaming AXI modules:
     writing only its own rows — the analog of the paper's line-buffer BRAMs.
     The padded tile is rounded up to whole (8, 128) tiles so the copy is
     aligned; the stencil reads its shifted windows straight from the tile.
+  * every kernel that takes the RGB frame (``cvt_color`` and the fused
+    kernels) reads it as three colour planes, ``[..., 3, H, W]``.  XLA
+    already stores an ``[..., H, W, 3]`` f32 frame plane-major in HBM, so
+    the ``moveaxis`` is a relabelling of the same bytes.  A channel-last
+    block would put the 3 channels on the 128 lanes: XLA would first copy
+    the frame into a lane-padded channel-minor layout (~42x the frame's
+    bytes) and the kernel would read that copy, and a channel-last halo
+    window cannot be DMA'd at all (Mosaic refuses the padded slice).
 
 Every kernel is compiled with ``vmem_limit_bytes=VMEM_BYTES``, the same
 scoped-VMEM figure the cost model and the row-block search plan with, and
@@ -82,27 +90,49 @@ def _leading_batch(call):
 
 
 # --------------------------------------------------------------------------- #
-# cvtColor: RGB → gray (elementwise, tiled rows)
+# cvtColor: RGB → gray (elementwise, tiled rows of three colour planes)
 # --------------------------------------------------------------------------- #
-def _cvt_kernel(img_ref, o_ref):
-    img = img_ref[...].astype(jnp.float32)
-    o_ref[...] = _gray(img[..., 0], img[..., 1], img[..., 2])
+CVT_BLOCK_BYTES = 8 * 1024**2   # double-buffered in + out blocks of cvt_color
 
 
-def cvt_color(img: jax.Array, *, row_block: int = ROW_BLOCK,
+def cvt_row_block(H: int, W: int) -> int:
+    """Rows per :func:`cvt_color` program: the largest multiple of 8 that
+    divides ``H`` and keeps the double-buffered 3-plane input and 1-plane
+    output blocks within ``CVT_BLOCK_BYTES``; ``H`` where no multiple of 8
+    divides it.  Row blocks carry no padded lanes, so a larger block only
+    saves per-program overhead (120 rows at 1080p, 144 at 720p)."""
+    row_bytes = 2 * (3 + 1) * _round_up(W, LANE) * _F32
+    fits = [rb for rb in range(SUBLANE, H + 1, SUBLANE)
+            if H % rb == 0 and rb * row_bytes <= CVT_BLOCK_BYTES]
+    if fits:
+        return fits[-1]
+    return SUBLANE if H % SUBLANE == 0 else H
+
+
+def _cvt_kernel(rgb_ref, o_ref):
+    rgb = rgb_ref[...].astype(jnp.float32)
+    o_ref[...] = _gray(rgb[0], rgb[1], rgb[2])
+
+
+def cvt_color(img: jax.Array, *, row_block: int | None = None,
               interpret: bool | None = None) -> jax.Array:
+    """``[H, W, 3]`` RGB → ``[H, W]`` f32 gray, read as three colour planes
+    (see the module docstring); ``row_block=None`` takes
+    :func:`cvt_row_block`."""
     H, W, C = img.shape
-    rb = row_block if H % row_block == 0 else H
+    rb = cvt_row_block(H, W) if row_block is None else row_block
+    rb = rb if H % rb == 0 else H
+    planes = jnp.moveaxis(img, -1, -3)
     return pl.pallas_call(
         _cvt_kernel,
         grid=(H // rb,),
-        in_specs=[pl.BlockSpec((rb, W, C), lambda i: (i, 0, 0))],
+        in_specs=[pl.BlockSpec((C, rb, W), lambda i: (0, i, 0))],
         out_specs=pl.BlockSpec((rb, W), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((H, W), jnp.float32),
         compiler_params=compiler_params(),
         interpret=interpret_mode(interpret),
         name="cvt_color",
-    )(img)
+    )(planes)
 
 
 # --------------------------------------------------------------------------- #
@@ -214,10 +244,9 @@ def convert_scale_abs(x: jax.Array, alpha: float = 1.0, beta: float = 0.0, *,
 # module was "too slow to use"; on TPU the cost model accepts it because the
 # eliminated HBM round-trips dominate (see repro.core.costmodel.fused_cost).
 #
-# The kernel reads the frame as three colour planes.  A channel-last halo
-# window cannot be DMA'd (Mosaic pads the 3-wide minor axis to 128 lanes and
-# refuses the slice), while XLA already stores an [H, W, 3] frame
-# plane-major in HBM, so the moveaxis below is a relabelling.
+# Like cvt_color, the kernel reads the frame as three colour planes (module
+# docstring): the moveaxis below relabels the plane-major frame XLA already
+# holds, and each program DMAs a plane-aligned halo window of it.
 
 def _fused_harris_kernel(img_hbm, o_ref, rgb_ref, gray_ref, *, rb: int,
                          W: int, block_size: int, k: float, with_csa: bool,

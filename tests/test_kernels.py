@@ -1,4 +1,6 @@
 """Per-kernel shape/dtype sweeps vs the ref.py oracles (interpret mode)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -66,12 +68,49 @@ def test_flash_attention_windowed_grads():
                                    atol=2e-4, rtol=2e-4)
 
 
-@pytest.mark.parametrize("H,W", [(8, 128), (64, 256), (33, 130)])
-def test_cvt_color_sweep(H, W):
-    img = jax.random.uniform(KEY, (H, W, 3)) * 255
-    np.testing.assert_allclose(np.asarray(cvt_color(img)),
-                               np.asarray(mh.cvt_color(img)),
+def _cvt_case(H, W, batch=None, row_block=None):
+    tag = "" if batch is None else f"batch{batch}-"
+    tag += f"{H}-{W}" + ("" if row_block is None else f"-rb{row_block}")
+    return pytest.param(H, W, batch, row_block, id=tag)
+
+
+# (33, 130), (45, 200), (21, 96): no multiple of 8 divides H, W is not a
+# multiple of 128; row_block 8 gives a grid of several programs
+@pytest.mark.parametrize("H,W,batch,row_block", [
+    _cvt_case(8, 128), _cvt_case(64, 256), _cvt_case(33, 130),
+    _cvt_case(45, 200), _cvt_case(64, 256, row_block=8),
+    _cvt_case(64, 256, batch=2, row_block=8), _cvt_case(33, 130, batch=3),
+    _cvt_case(21, 96, batch=2)])
+def test_cvt_color_sweep(H, W, batch, row_block):
+    shape = (H, W, 3) if batch is None else (batch, H, W, 3)
+    img = jax.random.uniform(KEY, shape) * 255
+    fn = functools.partial(cvt_color, row_block=row_block)
+    want = mh.cvt_color
+    if batch is not None:               # the served path vmaps stage 0
+        fn, want = jax.vmap(fn), jax.vmap(want)
+    np.testing.assert_allclose(np.asarray(fn(img)), np.asarray(want(img)),
                                rtol=1e-5, atol=1e-3)
+
+
+@pytest.mark.parametrize("H,W,rb", [(1080, 1920, 120), (720, 1280, 144),
+                                    (2160, 3840, 48), (33, 130, 33)])
+def test_cvt_row_block_rule(H, W, rb):
+    """The largest multiple of 8 dividing H whose double-buffered 3-plane
+    input and 1-plane output blocks fit ``CVT_BLOCK_BYTES``; H itself
+    where no multiple of 8 divides it."""
+    from repro.core.costmodel import VMEM_BYTES
+    from repro.kernels.harris import CVT_BLOCK_BYTES, cvt_row_block
+
+    def block_bytes(r):
+        return 2 * (3 + 1) * r * W * 4
+
+    got = cvt_row_block(H, W)
+    assert got == rb and H % got == 0
+    if H % 8 == 0:
+        assert got % 8 == 0
+        assert all(block_bytes(r) > CVT_BLOCK_BYTES
+                   for r in range(got + 8, H + 1, 8) if H % r == 0)
+    assert block_bytes(got) <= CVT_BLOCK_BYTES <= VMEM_BYTES
 
 
 @pytest.mark.parametrize("H,W", [(16, 128), (64, 256), (40, 136)])

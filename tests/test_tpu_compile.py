@@ -7,7 +7,9 @@ Nothing runs; the chip's compiler refuses here what it would refuse on the
 chip (unaligned copies, scoped-VMEM overflow, a ``pl.ANY`` input under
 ``vmap``).  Each case checks that the executable holds the Mosaic kernel.
 """
+import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -74,6 +76,34 @@ def test_harris_kernel_compiles_for_v5e(name, batch, one_chip,
     hlo = jax.jit(fn).lower(x).compile().as_text()
     assert "tpu_custom_call" in hlo
     assert name in compiled_kernels(hlo)  # the kernel's own pallas_call
+
+
+# a copy or transpose whose result is an f32 [..., 3] array laid out with the
+# channel axis minor (the first minor_to_major entry is the last axis)
+_RELAYOUT_RE = re.compile(
+    r"= f32\[((?:\d+,)*)3\]\{(\d+)[^}]*\} (?:copy|transpose)\(")
+
+
+def _channel_minor_relayouts(hlo: str) -> list[str]:
+    return [m.group(0) for m in _RELAYOUT_RE.finditer(hlo)
+            if int(m.group(2)) == m.group(1).count(",")]
+
+
+@pytest.mark.parametrize("batch", [None, 4], ids=["frame", "batch4"])
+def test_cvt_color_reads_planes_without_relayout(batch, one_chip,
+                                                 no_persistent_cache):
+    """cvt_color reads the frame XLA holds plane-major as three colour
+    planes: no lane-padded channel-minor copy of the frame, and no temp
+    buffer of its size (a channel-last block needed 1,061,683,200 bytes a
+    1080p frame)."""
+    fn = functools.partial(hk.cvt_color, interpret=False)
+    shape = (H, W, 3)
+    if batch is not None:
+        fn, shape = jax.vmap(fn), (batch,) + shape
+    x = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    compiled = jax.jit(fn).lower(x).compile()
+    assert _channel_minor_relayouts(compiled.as_text()) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 1024**2
 
 
 # N x d activations, [d] scale, [d, d] weight: rmsnorm_matmul's blocks hold
