@@ -1,27 +1,22 @@
 """Closed loop: one producer submits back to back from a pool of distinct
-frames resident on the device, held back only by the server's own
-backpressure (a full request queue blocks ``submit``).
+requests drawn from the app's source and resident on the device, held
+back only by the server's own backpressure (a full request queue blocks
+``submit``).
 
-Traffic keys: ``pool`` (distinct frames), ``check_every`` (one frame in
-this many is kept for the correctness check).
+Traffic keys: ``pool`` (distinct requests), ``check_every`` (one request
+in this many is kept for the correctness check).
 """
 from __future__ import annotations
 
 import numpy as np
 
-from chipbench import frames
-
 
 class Load:
     on_host = False
 
-    def __init__(self, traffic: dict, shape: tuple[int, int], seed: int):
-        n = int(traffic["pool"])
-        self.pool = frames.device_pool(n, *shape, seed)
+    def __init__(self, traffic: dict, source, seed: int):
+        self.pool = source.device_pool(int(traffic["pool"]))
         self.rng = np.random.default_rng([seed, 0])
-
-    def warm_frame(self):
-        return self.pool[0]
 
     def schedule(self, seconds: float):
         """Endless ``(due_s, pool_index)``; a closed loop has no due time.

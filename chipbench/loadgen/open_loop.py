@@ -1,7 +1,8 @@
 """Open loop: ``streams`` independent cameras at ``fps`` frames/s each,
-each periodic with a random phase.  A frame is uploaded from a pool of
-distinct host frames (``jax.device_put``) at its due time, as a camera's
-decoded frame would be, whether or not the server has caught up.
+each periodic with a random phase.  A frame (a request of the app's
+source) is uploaded from a pool of distinct host frames
+(``jax.device_put``) at its due time, as a camera's decoded frame would
+be, whether or not the server has caught up.
 
 The phases are drawn from the mix's own ``phase_seed``, not from the
 run's seed, so every run sends frames at the same instants: how the
@@ -17,25 +18,17 @@ from __future__ import annotations
 
 import numpy as np
 
-from chipbench import frames
-
 
 class Load:
     on_host = True
 
-    def __init__(self, traffic: dict, shape: tuple[int, int], seed: int):
+    def __init__(self, traffic: dict, source, seed: int):
         self.streams = int(traffic["streams"])
         self.fps = float(traffic["fps"])
-        n = int(traffic["pool"])
-        self.host_frames = frames.host_pool(n, *shape, seed)
+        self.host_frames = source.host_pool(int(traffic["pool"]))
         self.rng = np.random.default_rng([seed, 1])
         self.phase = np.random.default_rng(int(traffic["phase_seed"])).random(
             self.streams) / self.fps
-
-    def warm_frame(self):
-        import jax
-
-        return jax.device_put(self.host_frames[0])
 
     def schedule(self, seconds: float) -> list[tuple[float, int]]:
         """``(due_s, pool_index)`` of every frame due in the window, in due

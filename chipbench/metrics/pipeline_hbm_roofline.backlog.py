@@ -11,9 +11,9 @@ is summed over the chips, and the peak is one chip's.
 
 
 def read(run):
-    done = len(run.ready_in_window())
-    if run.trace is None or not done:
+    pixels = sum(f.size for f in run.ready_in_window())
+    if run.trace is None or not pixels:
         return None
     busy = sum(run.trace.busy_s.values())
-    need = done * 16 * run.frame_pixels
+    need = 16 * pixels
     return 100.0 * need / (busy * run.peak["hbm_bytes_per_s"])

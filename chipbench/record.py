@@ -1,4 +1,5 @@
-"""What one run records, and the arithmetic every metric reader shares."""
+"""What one run records, what an app hands it, and the arithmetic every
+metric reader shares."""
 from __future__ import annotations
 
 import math
@@ -14,9 +15,21 @@ class FrameRecord:
     pool_index: int              # which pool frame it carried
     t_due: float | None          # open loop: when it was due; closed: None
     t_submit: float              # when the generator began to send it
+    size: int | None = None      # its work, in its app's unit (pixels, tokens)
     t_ready: float | None = None  # when the client held its ready result
     queue_ms: float | None = None  # submit to batch pickup (server's clock)
     error: str | None = None
+
+
+@dataclass
+class Served:
+    """What an app's ``build`` hands the run: the executor and the
+    ``RequestQueueServer`` in front of it, built and warmed on every
+    shape the source holds, and the lines that describe its plan."""
+
+    executor: Any
+    server: Any
+    plan_lines: list[str]
 
 
 @dataclass
@@ -59,11 +72,6 @@ class RunData:
             return None
         total = sum(s[counter] for s in self.executor["per_stage"])
         return total / self.executor["tokens_retired"]
-
-    @property
-    def frame_pixels(self) -> int:
-        f = self.config["frame"]
-        return int(f["height"]) * int(f["width"])
 
 
 def percentile(values, q: float) -> float | None:

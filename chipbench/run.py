@@ -3,14 +3,30 @@
     python3 chipbench/run.py --workload <cell> --seed <n> --seconds <s> \\
         --trace <0|1>
 
-The cell names a configuration (``configs/<config>.json``: frame size,
-batching, chips) and a traffic mix (``traffic/<traffic>.json``, whose
-``kind`` names its generator ``loadgen/<kind>.py``), both found through
-``BENCHMARK.json``.  The run builds the served Harris pipeline the way the
-program's serving demo does, warms every shape, then drives the
-``RequestQueueServer`` with the mix for ``--seconds``.  Afterwards it
-compares a sample of the served frames, drawn from the seed, with the
-benchmark's own numpy float32 reference.
+The cell names a configuration (``configs/<config>.json``: the app's
+sizes, batching, chips) and a traffic mix (``traffic/<traffic>.json``,
+whose ``kind`` names its generator ``loadgen/<kind>.py``), both found
+through ``BENCHMARK.json``.  The configuration's ``app`` names
+``apps/<app>.py``, which provides
+
+- ``REQUIRED``: the configuration keys it reads;
+- ``inputs(config, seed) -> Source``: the seeded requests.  A source has
+  ``device_pool(n)`` and ``host_pool(n)`` (items may differ in shape),
+  ``warm_items()`` (one item of every shape the pools can hold) and
+  ``size(item)`` (the item's work, in the app's ``UNIT``);
+- ``build(config, source, devices) -> record.Served``: the served system,
+  warmed on every item of ``warm_items()``;
+- ``check(outputs, items, source) -> {name: {"value", "limit"}}``: the
+  served outputs against the benchmark's own reference of their inputs
+  (and of whatever else the source drew from the seed, such as weights);
+- ``control(items, source)``: that reference one precision lower, put in
+  the served path's place; ``control.py`` judges it by ``check``, and a
+  run never calls it.
+
+The run builds the app, then drives its ``RequestQueueServer`` with the
+mix for ``--seconds``.  Afterwards it checks a sample of the served
+outputs, drawn from the seed.  A "frame" here, in ``frames_per_s`` and in
+``record.FrameRecord``, is one served request of the app.
 
 The last stdout line is one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics`` (the cell's end-to-end metrics with ``--trace 0``,
@@ -47,13 +63,9 @@ for _p in (os.path.join(ROOT, "src"), ROOT):
 
 import numpy as np  # noqa: E402
 
-from chipbench import reference, trace_reduce  # noqa: E402
+from chipbench import trace_reduce  # noqa: E402
 from chipbench.record import FrameRecord, RunData, percentile  # noqa: E402
 
-# widest gap, in gray levels, between a served frame and the reference.
-# Served frames read 1.5e-5 to 3.1e-5 on a TPU v5e, the bfloat16 control
-# 7.7 and more (PERF.md gives the readings)
-GAP_LIMIT = 0.1
 WAIT_PAST_CLOSE_S = 60.0    # how long a frame due in the window may take
 MAX_KEPT = 128              # served frames held on the device for the check
 AUTOTUNE_DIR = os.path.join(ROOT, ".autotune-cache")
@@ -71,6 +83,7 @@ class Cell:
     traffic: dict
     end_to_end: list
     per_layer: list
+    app: object       # the module apps/<app>.py that the config names
 
 
 def _json(path: str):
@@ -87,8 +100,16 @@ def _module(kind: str, name: str):
     return mod
 
 
+def app_of(config: dict):
+    """The app module that ``config`` names; there is no default."""
+    if "app" not in config:
+        raise KeyError(f"configuration {config.get('name')!r} names no app")
+    return _module("apps", config["app"])
+
+
 def resolve(name: str, bench: dict | None = None) -> Cell:
-    """The cell ``name`` with its configuration, traffic and metrics."""
+    """The cell ``name`` with its configuration, app, traffic and
+    metrics."""
     bench = bench or _json(os.path.join(ROOT, "BENCHMARK.json"))
     (w,) = [w for w in bench["workloads"] if w["name"] == name] or [None]
     if w is None:
@@ -98,12 +119,13 @@ def resolve(name: str, bench: dict | None = None) -> Cell:
     def mine(m):
         return "workloads" not in m or name in m["workloads"]
 
-    return Cell(name=name, chips=int(w["chips"]),
-                config=_json(os.path.join(ROOT, c["file"])),
+    config = _json(os.path.join(ROOT, c["file"]))
+    return Cell(name=name, chips=int(w["chips"]), config=config,
                 traffic=_json(os.path.join(BENCH, "traffic",
                                            w["traffic"] + ".json")),
                 end_to_end=[m for m in bench["end_to_end"] if mine(m)],
-                per_layer=[m for m in bench["per_layer"] if mine(m)])
+                per_layer=[m for m in bench["per_layer"] if mine(m)],
+                app=app_of(config))
 
 
 def chip_devices(chips: int) -> list:
@@ -141,65 +163,9 @@ def wrap_in_span(obj, attr: str, name: str, spans) -> None:
 
 
 # --------------------------------------------------------------------------- #
-# the system under test
-# --------------------------------------------------------------------------- #
-@dataclass
-class Served:
-    offloaded: object
-    executor: object
-    server: object
-
-
-def build(config: dict, warm_frame) -> Served:
-    """The pipeline as ``repro.launch.serve.serve_pipeline_demo`` builds it,
-    warmed on ``warm_frame``; deployment settings from ``config``."""
-    from repro.core import DeviceInventory, courier_offload
-    from repro.core.partition import widen_for_deployment
-    from repro.core.tracer import Library
-    from repro.launch.serve import (RequestQueueServer,
-                                    replication_aware_batching)
-    from repro.models.harris import corner_harris_demo, make_harris_db
-
-    db = make_harris_db(with_hw=True)
-    off = courier_offload(corner_harris_demo(Library(db)), warm_frame, db=db)
-    inventory = (DeviceInventory.detect(limit=config["devices"])
-                 if config["devices"] else None)
-    plan = off.pipeline.plan
-    budget = (plan.n_stages + config["extra_workers"]
-              if config["extra_workers"] is not None else None)
-    replicas, stage_devices = widen_for_deployment(
-        plan, off.pipeline.ir, worker_budget=budget, inventory=inventory)
-    max_batch, max_wait_ms = config["max_batch"], config["max_wait_ms"]
-    if replicas is not None:
-        max_batch, max_wait_ms = replication_aware_batching(
-            plan, max_batch=max_batch, max_wait_ms=max_wait_ms)
-    ex = off.pipeline.executor(microbatch=max_batch, pad_microbatches=True,
-                               replicas=replicas, devices=stage_devices,
-                               inventory=inventory)
-    ex.warmup(warm_frame)
-    srv = RequestQueueServer(ex, max_batch=max_batch,
-                             max_wait_ms=max_wait_ms)
-    return Served(off, ex, srv)
-
-
-def plan_lines(s: Served) -> list[str]:
-    pipe, ex, srv = s.offloaded.pipeline, s.executor, s.server
-    lines = [f"plan: {pipe.plan.n_stages} stages; server max_batch "
-             f"{srv.max_batch}, max_wait_ms {srv.max_wait_ms:g}; executor "
-             f"microbatch {ex.microbatch}, pool {ex.pool}"]
-    for k, st in enumerate(pipe.plan.stages):
-        nodes = ", ".join(f"{pipe.ir.node(n).fn_key}="
-                          f"{pipe.ir.node(n).placement.kind}"
-                          for n in st.node_names)
-        lines.append(f"plan:   stage {k}: {nodes}; replicas {st.replicas}; "
-                     f"devices {list(st.devices)}")
-    return lines
-
-
-# --------------------------------------------------------------------------- #
 # the measured window
 # --------------------------------------------------------------------------- #
-def drive(load, srv, t0: float, seconds: float, spans, keep):
+def drive(load, size, srv, t0: float, seconds: float, spans, keep):
     """Send the mix from ``t0`` for ``seconds``; wait for every frame sent.
 
     Returns the frame records and the served frames kept for the check
@@ -207,6 +173,7 @@ def drive(load, srv, t0: float, seconds: float, spans, keep):
     waits for each result in order and holds it ready; the client keeps
     the result of each frame whose index ``keep`` accepts (at most
     ``MAX_KEPT``) and drops every other, and the request's frame with it.
+    ``size(item)`` gives each frame's record its size.
     """
     import jax
 
@@ -234,7 +201,7 @@ def drive(load, srv, t0: float, seconds: float, spans, keep):
                     x = load.frame(p)
                 with spans("submit"):
                     r = srv.submit(x)
-                sent.put((FrameRecord(i, p, t_due, t_submit), r))
+                sent.put((FrameRecord(i, p, t_due, t_submit, size(x)), r))
         except BaseException as e:
             errors.append(e)
         finally:
@@ -308,16 +275,16 @@ def _run_cell(cell, seed, seconds, trace, devices, peak, t_start, log,
               compiles, full_gc) -> dict:
     import jax
 
-    cfg = cell.config
-    shape = (int(cfg["frame"]["height"]), int(cfg["frame"]["width"]))
+    cfg, app = cell.config, cell.app
+    source = app.inputs(cfg, seed)
     load = _module("loadgen", cell.traffic["kind"]).Load(
-        cell.traffic, shape, seed)
+        cell.traffic, source, seed)
     # one frame in check_every, from an offset drawn from the seed: every
     # seed keeps as many frames, spread over the window
     every = int(cell.traffic["check_every"])
     offset = int(np.random.default_rng([seed, 2]).integers(every))
-    served = build(cfg, load.warm_frame())
-    for line in plan_lines(served):
+    served = app.build(cfg, source, devices)
+    for line in served.plan_lines:
         log(line)
     srv, ex = served.server, served.executor
     spans = span_factory(trace)
@@ -339,7 +306,7 @@ def _run_cell(cell, seed, seconds, trace, devices, peak, t_start, log,
         t0 = time.perf_counter()
         setup_s = t0 - t_start
         threads, records, kept, errors = drive(
-            load, srv, t0, seconds, spans,
+            load, source.size, srv, t0, seconds, spans,
             lambda i: (i + offset) % every == 0)
         time.sleep(max(t0 + seconds - time.perf_counter(), 0.0))
         t1 = time.perf_counter()
@@ -374,6 +341,8 @@ def _run_cell(cell, seed, seconds, trace, devices, peak, t_start, log,
     log(f"window: {seconds:g} s; frames attempted {len(run.attempted())}, "
         f"ready in the window {len(run.ready_in_window())}; compiles in the "
         f"window {in_window} (stage programs {stage_compiles})")
+    log(f"sizes: {sum(f.size for f in run.attempted())} {app.UNIT} over "
+        f"the frames attempted")
     log(f"gc: full collections in the window {len(gc_window)}, longest "
         f"{max(gc_window, default=0.0) * 1e3:.3f} ms")
     if late:
@@ -390,26 +359,24 @@ def _run_cell(cell, seed, seconds, trace, devices, peak, t_start, log,
             log(f"trace: {dev} busy {busy:.6f} s of "
                 f"{summary.window_s:.6f} s")
 
-    # the check, off the window: served frames against the reference
+    # the check, off the window: served frames against the app's reference
     host_out = _host(kept)
     kept.clear()
     pool_of = {f.index: f.pool_index for f in run.frames}
-    need = sorted({pool_of[i] for i in host_out})
-    pool = {p: load.pool_frame(p) for p in need}
+    keys = sorted(host_out)
+    pool = {p: load.pool_frame(p) for p in sorted({pool_of[i] for i in keys})}
     load.release()
     del served, srv, ex
-    h = cfg["harris"]
-    refs = {p: reference.harris_demo(pool[p], h["block_size"], h["k"])
-            for p in need}
-    gap = max((reference.max_gap(v, refs[pool_of[i]])
-               for i, v in host_out.items()), default=None)
+    checks = app.check([host_out[i] for i in keys],
+                       [pool[pool_of[i]] for i in keys], source)
     attempted = run.attempted()
     failed = sum(f.error is not None for f in attempted)
-    log(f"check: {len(host_out)} served frames compared, from "
-        f"{len(need)} distinct pool frames")
-    checks = {"max_gray_gap": {"value": gap, "limit": GAP_LIMIT},
-              "frames_lost": {"value": failed, "limit": 0}}
-    correct = gap is not None and gap <= GAP_LIMIT and failed == 0
+    log(f"check: {len(keys)} served frames compared, from "
+        f"{len(pool)} distinct pool frames")
+    checks["frames_lost"] = {"value": failed, "limit": 0}
+    correct = bool(keys) and all(c["value"] is not None
+                                 and c["value"] <= c["limit"]
+                                 for c in checks.values())
 
     metrics = {}
     for m in (cell.per_layer if trace else cell.end_to_end):

@@ -1,17 +1,19 @@
 """Reduce a profiler trace of one measured window to device busy time, the
-device's top operations and its idle gaps, each gap named by what the host
-was doing in it.
+time of every device operation and every program, and the device's idle
+gaps, each gap named by what the host was doing in it.
 
 The trace is the ``.xplane.pb`` that ``jax.profiler`` writes.  Device
 planes are ``/device:TPU:<n>``; their operations are the events of the
 ``XLA Ops`` line, each named by its HLO instruction (a Pallas kernel's
 carries its ``pallas_call`` name, as in ``vmap_cvt_color_.1``).  The
 ``Async XLA Ops`` line (copies that overlap compute) is not busy time of
-its own.  Host
-spans are the ``jax.profiler.TraceAnnotation`` events the benchmark
-records on its own threads and around its calls into the server:
-``window`` bounds the measured window, and the names in :data:`HOST_SPANS`
-say what the host was doing.
+its own.  The ``XLA Modules`` line holds the programs those ops ran in,
+each named by its jitted function and fingerprint (a stage program of the
+served pipeline is ``jit_stage_<calls>(<fingerprint>)``).  Host spans are
+the ``jax.profiler.TraceAnnotation`` events the benchmark records on its
+own threads and around its calls into the server: ``window`` bounds the
+measured window, and the names in :data:`HOST_SPANS` say what the host
+was doing.
 """
 from __future__ import annotations
 
@@ -21,6 +23,7 @@ from dataclasses import dataclass, field
 
 DEVICE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
 WINDOW = "window"
 # what the host can be doing while a device idles.  Each instant of a gap
 # goes to the first of these that was open then: work on the host's path
@@ -40,6 +43,8 @@ class Trace:
 
     devices: dict[str, list[tuple[str, float, float]]] = field(
         default_factory=dict)
+    modules: dict[str, list[tuple[str, float, float]]] = field(
+        default_factory=dict)
     host: list[tuple[str, float, float]] = field(default_factory=list)
 
 
@@ -49,6 +54,9 @@ class Summary:
     busy_s: dict[str, float]                 # per device plane
     device_ops: list[tuple[str, float]]      # top ops, seconds, all devices
     idle_gaps: list[tuple[str, float]]       # idle seconds by host activity
+    # every op's and every program's seconds in the window, all devices
+    op_s: dict[str, float] = field(default_factory=dict)
+    module_s: dict[str, float] = field(default_factory=dict)
 
     @property
     def mean_busy_s(self) -> float:
@@ -82,12 +90,17 @@ def load(path: str) -> Trace:
     wanted = set(HOST_SPANS) | {WINDOW}
     for plane in pd.planes:
         if plane.name.startswith(DEVICE_PREFIX):
-            ops = []
+            ops, modules = [], []
             for line in plane.lines:
                 if line.name == OPS_LINE:
                     ops.extend((op_name(e.name), float(e.start_ns),
                                 float(e.duration_ns)) for e in line.events)
+                elif line.name == MODULES_LINE:
+                    modules.extend((e.name, float(e.start_ns),
+                                    float(e.duration_ns))
+                                   for e in line.events)
             tr.devices[plane.name] = ops
+            tr.modules[plane.name] = modules
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 tr.host.extend((e.name, float(e.start_ns),
@@ -126,6 +139,14 @@ def _minus(a: list[tuple[float, float]], b: list[tuple[float, float]]):
     return out
 
 
+def _clipped_s(events, w0: float, w1: float, into: dict) -> None:
+    """Add each event's seconds inside [w0, w1] to ``into[name]``."""
+    for name, s, d in events:
+        t = max(0.0, min(s + d, w1) - max(s, w0))
+        if t > 0:
+            into[name] = into.get(name, 0.0) + t / 1e9
+
+
 def reduce(tr: Trace) -> Summary:
     windows = [(s, s + d) for n, s, d in tr.host if n == WINDOW]
     if len(windows) != 1:
@@ -138,14 +159,13 @@ def reduce(tr: Trace) -> Summary:
                        w0, w1) for k in HOST_SPANS}
     busy: dict[str, float] = {}
     ops: dict[str, float] = {}
+    modules: dict[str, float] = {}
     idle: dict[str, float] = {}
     for dev, events in sorted(tr.devices.items()):
         merged = _union([(s, s + d) for _, s, d in events], w0, w1)
         busy[dev] = sum(e - s for s, e in merged) / 1e9
-        for name, s, d in events:
-            t = max(0.0, min(s + d, w1) - max(s, w0))
-            if t > 0:
-                ops[name] = ops.get(name, 0.0) + t / 1e9
+        _clipped_s(events, w0, w1, ops)
+        _clipped_s(tr.modules.get(dev, ()), w0, w1, modules)
         gaps = _minus([(w0, w1)], merged)
         for k in HOST_SPANS:
             rest = _minus(gaps, spans[k])
@@ -159,4 +179,4 @@ def reduce(tr: Trace) -> Summary:
     top = sorted(ops.items(), key=lambda kv: -kv[1])[:TOP]
     gaps = sorted(idle.items(), key=lambda kv: -kv[1])[:TOP]
     return Summary(window_s=(w1 - w0) / 1e9, busy_s=busy, device_ops=top,
-                   idle_gaps=gaps)
+                   idle_gaps=gaps, op_s=ops, module_s=modules)

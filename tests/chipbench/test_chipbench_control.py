@@ -32,8 +32,10 @@ def _small(cell_name):
 @pytest.mark.parametrize("cell", [
     "harris-1080p.backlog", "harris-720p.cameras"])
 def test_bfloat16_control_fails_the_limit(cell, seed):
-    gap = control.control_gap(_small(cell), seed, n_frames=2)
-    assert gap > 10 * bench.GAP_LIMIT
+    small = _small(cell)
+    gap = control.control_checks(small, seed, n_items=2)["max_gray_gap"]
+    assert gap["limit"] == small.app.GAP_LIMIT
+    assert gap["value"] > 10 * gap["limit"]
 
 
 def test_reference_against_itself_and_a_wrong_shape():

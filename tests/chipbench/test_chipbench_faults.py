@@ -101,7 +101,7 @@ def four_chip_cell() -> bench.Cell:
         traffic = json.load(f)
     return bench.Cell(name="harris-1080p-4chip.backlog", chips=4,
                       config=config, traffic=traffic, end_to_end=[],
-                      per_layer=[])
+                      per_layer=[], app=bench.app_of(config))
 
 
 def small_run(cell: bench.Cell, fault: str | None, devices: list) -> dict:
@@ -113,21 +113,20 @@ def small_run(cell: bench.Cell, fault: str | None, devices: list) -> dict:
         # enough cameras that most groups hold several frames, so that a
         # fault in how a batch is served shows in every run
         cell.traffic["streams"] = 24
-    build = bench.build
+    # a module of this run's own: the planted fault goes with it
+    cell.app = bench.app_of(cell.config)
+    build = cell.app.build
 
-    def broken(config, warm_frame):
-        served = build(config, warm_frame)
+    def broken(config, source, devices):
+        served = build(config, source, devices)
         if fault is not None:
             FAULTS[fault](served.executor)
         return served
 
-    bench.build = broken
-    try:
-        return bench.run_cell(cell, SEED, SECONDS, False, devices,
-                              {"hbm_bytes_per_s": 1e11},
-                              t_start=time.perf_counter(), log=lambda s: None)
-    finally:
-        bench.build = build
+    cell.app.build = broken
+    return bench.run_cell(cell, SEED, SECONDS, False, devices,
+                          {"hbm_bytes_per_s": 1e11},
+                          t_start=time.perf_counter(), log=lambda s: None)
 
 
 @pytest.mark.parametrize("fault", [None, "answer_altered", "half_batch"])

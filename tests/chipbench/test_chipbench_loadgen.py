@@ -1,5 +1,6 @@
-"""The load generators, the frame pools and the statistics every metric
-reader shares: deterministic per seed, and taken over all requests."""
+"""The load generators, the frame pools, the Harris app's source of them
+and the statistics every metric reader shares: deterministic per seed, and
+taken over all requests."""
 from __future__ import annotations
 
 import os
@@ -16,16 +17,23 @@ if ROOT not in sys.path:
 from chipbench import frames  # noqa: E402
 from chipbench import run as bench  # noqa: E402
 from chipbench.record import FrameRecord, RunData, percentile  # noqa: E402
+from chipbench.trace_reduce import Summary  # noqa: E402
 
 BIG_SEED = 2**33 + 12345        # seeds beyond 32 bits must work
 CAMERAS = {"kind": "open_loop", "streams": 5, "fps": 30, "phase_seed": 3,
            "pool": 4, "check_every": 8}
 BACKLOG = {"kind": "closed_loop", "pool": 4, "check_every": 8}
+HARRIS = bench._module("apps", "harris")
+
+
+def _source(seed, shape=(16, 24)):
+    return HARRIS.inputs({"frame": {"height": shape[0], "width": shape[1]},
+                          "harris": {"block_size": 2, "k": 0.04}}, seed)
 
 
 def _load(traffic, seed, shape=(16, 24)):
-    return bench._module("loadgen", traffic["kind"]).Load(traffic, shape,
-                                                          seed)
+    return bench._module("loadgen", traffic["kind"]).Load(
+        traffic, _source(seed, shape), seed)
 
 
 @pytest.mark.parametrize("seed", [0, 7, BIG_SEED])
@@ -39,6 +47,26 @@ def test_frame_pools_are_deterministic_per_seed(seed):
     assert not np.array_equal(a[0], a[1])
     other = frames.host_pool(3, 16, 24, seed + 1)
     assert not np.array_equal(a[0], other[0])
+
+
+@pytest.mark.parametrize("seed", [0, 7, 2**33 + 5])
+def test_harris_source_pools_are_the_frame_pools(seed):
+    src = _source(seed, (64, 96))
+    for got, want in (
+            (src.device_pool(3), frames.device_pool(3, 64, 96, seed)),
+            (src.host_pool(3), frames.host_pool(3, 64, 96, seed))):
+        assert len(got) == 3
+        for x, y in zip(got, want):
+            assert type(x) is type(y) and x.dtype == y.dtype
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    (warm,) = src.warm_items()
+    assert warm.shape == (64, 96, 3) and src.size(warm) == 64 * 96
+    # the loadgens hold the source's pools
+    closed = _load(dict(BACKLOG, pool=3), seed, (64, 96))
+    opened = _load(dict(CAMERAS, pool=3), seed, (64, 96))
+    for p in range(3):
+        np.testing.assert_array_equal(closed.pool_frame(p),
+                                      opened.pool_frame(p))
 
 
 @pytest.mark.parametrize("seed", [1, BIG_SEED])
@@ -79,8 +107,7 @@ def test_percentile_matches_numpy_linear():
 
 
 def _run(frames_):
-    return RunData(config={"frame": {"height": 2, "width": 2}},
-                   peak={}, seconds=1.0, t0=0.0, t1=1.0, setup_s=0.0,
+    return RunData(config={}, peak={}, seconds=1.0, t0=0.0, t1=1.0, setup_s=0.0,
                    frames=frames_)
 
 
@@ -114,3 +141,21 @@ def test_frames_per_s_counts_results_ready_inside_the_window():
     assert bench._module("metrics", "frames_per_s").read(run) == 9.0
     assert len(run.attempted()) == 10
     assert bench._module("metrics", "latency_p50_ms").read(run) is None
+
+
+def test_hbm_roofline_counts_16_bytes_a_pixel_of_the_frames_completed():
+    # 1080p frames, nine ready inside the window (one late, one lost):
+    # 16 bytes a pixel of each over busy time at the peak
+    fs = [FrameRecord(i, 0, None, i / 10, size=1080 * 1920,
+                      t_ready=i / 10 + 0.05) for i in range(11)]
+    fs[3].error = "lost"
+    run = _run(fs)
+    reader = bench._module("metrics", "pipeline_hbm_roofline.backlog")
+    assert reader.read(run) is None
+    run.peak = {"hbm_bytes_per_s": 8.0e11}
+    run.trace = Summary(window_s=1.0, busy_s={"/device:TPU:0": 0.25},
+                        device_ops=[], idle_gaps=[])
+    want = 100.0 * 9 * 16 * 1080 * 1920 / (0.25 * 8.0e11)
+    assert reader.read(run) == pytest.approx(want)
+    run.frames = []
+    assert reader.read(run) is None

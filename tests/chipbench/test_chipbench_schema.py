@@ -67,13 +67,20 @@ def test_bounds():
     assert all("bound" not in m for m in SPEC["per_layer"])
 
 
+COMMON_KEYS = {"app", "source", "assumed", "reduced", "chips", "max_batch",
+               "max_wait_ms", "devices", "extra_workers"}
+
+
 @pytest.mark.parametrize("cell", CELLS)
 def test_cell_resolves_every_part_by_name(cell):
     c = bench.resolve(cell, SPEC)
     (w,) = [w for w in SPEC["workloads"] if w["name"] == cell]
     assert c.chips == w["chips"] == c.config["chips"]
-    assert {"frame", "harris", "max_batch", "max_wait_ms", "devices",
-            "extra_workers", "source", "assumed", "reduced"} <= set(c.config)
+    assert os.path.isfile(os.path.join(ROOT, "chipbench", "apps",
+                                       c.config["app"] + ".py"))
+    assert COMMON_KEYS | set(c.app.REQUIRED) <= set(c.config)
+    for part in ("inputs", "build", "check"):
+        assert callable(getattr(c.app, part))
     load = os.path.join(ROOT, "chipbench", "loadgen",
                         c.traffic["kind"] + ".py")
     assert os.path.isfile(load)
@@ -81,6 +88,20 @@ def test_cell_resolves_every_part_by_name(cell):
         assert callable(bench._module("metrics", m["name"]).read)
     assert "setup_s" in {m["name"] for m in c.end_to_end}
     assert len(c.end_to_end) >= 2 and len(c.per_layer) >= 1
+
+
+def test_resolve_refuses_a_configuration_without_an_app(tmp_path):
+    c = SPEC["configs"][0]
+    (cell, *_) = [w["name"] for w in SPEC["workloads"]
+                  if w["config"] == c["name"]]
+    with open(os.path.join(ROOT, c["file"])) as f:
+        config = json.load(f)
+    del config["app"]
+    path = tmp_path / "no-app.json"
+    path.write_text(json.dumps(config))
+    spec = dict(SPEC, configs=[dict(c, file=str(path))])
+    with pytest.raises(KeyError, match="names no app"):
+        bench.resolve(cell, spec)
 
 
 def test_configs_are_files_of_their_own_under_paths():

@@ -1,6 +1,7 @@
-"""The reduction from a profiler trace to busy time, top device operations
-and idle gaps: on a synthetic trace with known intervals, and on a small
-trace recorded on a TPU v5e chip."""
+"""The reduction from a profiler trace to busy time, the time of every
+device operation and program, top device operations and idle gaps: on a
+synthetic trace with known intervals, and on a small trace recorded on a
+TPU v5e chip."""
 from __future__ import annotations
 
 import glob
@@ -50,6 +51,19 @@ def test_top_ops_sum_clipped_durations_over_devices():
     assert ops == pytest.approx({"fusion": 0.05, "cvt_color": 0.02,
                                  "copy.1": 0.015, "corner_harris": 0.01})
     assert [n for n, _ in s.device_ops][0] == "fusion"
+
+
+def test_every_op_and_program_sums_clipped_durations_over_devices():
+    # stage programs as the pipeline names them, after their library calls
+    a, b = ("jit_stage_cvtColor_cornerHarris(1)",
+            "jit_stage_convertScaleAbs(2)")
+    t = _synthetic()
+    t.modules = {"/device:TPU:0": [(a, 10 * MS, 30 * MS),
+                                   (b, 90 * MS, 20 * MS)],
+                 "/device:TPU:1": [(a, -5 * MS, 55 * MS)]}
+    s = tr.reduce(t)
+    assert s.op_s == pytest.approx(dict(s.device_ops))
+    assert s.module_s == pytest.approx({a: 0.080, b: 0.010})
 
 
 def test_each_idle_instant_goes_to_the_first_host_span_open_then():
@@ -117,3 +131,60 @@ def test_a_trace_recorded_on_the_chip(path):
     assert all(sec > 0 for _, sec in s.device_ops + s.idle_gaps)
     total_idle = sum(sec for _, sec in s.idle_gaps)
     assert total_idle == pytest.approx(s.window_s - s.mean_busy_s, rel=1e-6)
+
+
+# reduce() of the recorded trace, as the reduction read it before it kept
+# every op's and program's seconds: those additions must leave it as it was
+RECORDED_1080P = {
+    "window_s": 0.300144454,
+    "busy_s": {"/device:TPU:0": 0.200124661},
+    "device_ops": [
+        ("vmap_cvt_color_.1 f32[4,1080,1920]{2,1,0:T(8,128)S(1)}",
+         0.106122737),
+        ("copy.1 f32[4,1080,1920,3]{3,2,1,0:T(8,128)}", 0.072661792),
+        ("corner_harris.1 f32[4,1080,1920]{2,1,0:T(8,128)}",
+         0.005785083999999999),
+        ("pad_maximum_fusion f32[4,1080,1920,3]{2,1,3,0:T(8,128)}",
+         0.003686582),
+        ("copy.1 f32[1,1080,1920,3]{2,1,3,0:T(8,128)}",
+         0.0036167499999999997),
+        ("copy-done f32[4,1080,1920]{2,1,0:T(8,128)S(1)}",
+         0.0023106319999999995),
+        ("vmap_convert_scale_abs_.1 f32[4,1080,1920]{2,1,0:T(8,128)}",
+         0.0022600280000000003),
+        ("copy.1 f32[1080,1920]{1,0:T(8,128)}", 0.0010628280000000003),
+        ("divide_multiply_fusion f32[4,1080,1920]{2,1,0:T(8,128)}",
+         0.0005599919999999999),
+        ("constant_dynamic-slice_fusion f32[1,1080,1920]{2,1,0:T(8,128)}",
+         0.0005534460000000002)],
+    "idle_gaps": [("retire", 0.089457991), ("dispatch", 0.004771984),
+                  ("no_host_span", 0.004587978),
+                  ("batcher_wait", 0.00072301), ("submit", 0.00047883)],
+}
+RECORDED_1080P_PATH = os.path.join(ROOT, "chipbench", "testdata",
+                                   "harris-1080p-backlog-300ms.xplane.pb")
+
+
+def test_the_recorded_trace_reduces_as_before():
+    s = tr.reduce(tr.load(RECORDED_1080P_PATH))
+    for key, want in RECORDED_1080P.items():
+        assert getattr(s, key) == want, key
+
+
+def test_the_recorded_trace_keeps_every_op_and_program():
+    t = tr.load(RECORDED_1080P_PATH)
+    s = tr.reduce(t)
+    ((w0, w1),) = [(b, b + d) for n, b, d in t.host if n == tr.WINDOW]
+    in_window = {n for n, b, d in t.devices["/device:TPU:0"]
+                 if min(b + d, w1) > max(b, w0)}
+    assert set(s.op_s) == in_window and len(s.op_s) > tr.TOP
+    top = sorted(s.op_s.items(), key=lambda kv: -kv[1])[:tr.TOP]
+    assert top == s.device_ops
+    assert sum(s.op_s.values()) >= sum(v for _, v in s.device_ops)
+    # recorded before the stage programs were named after their calls:
+    # all three are ``jit_stage(<fingerprint>)``
+    stages = [n for n in s.module_s if n.startswith("jit_stage")]
+    assert len(stages) == 3
+    assert all(v > 0 for v in s.module_s.values())
+    assert sum(s.module_s.values()) <= s.window_s
+
