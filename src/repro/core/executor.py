@@ -23,12 +23,15 @@ pool:
 * **Per-stage micro-batching** — consecutive tokens whose input
   shapes/dtypes agree can be stacked along a new leading axis and pushed
   through ``jax.vmap``-ed stage functions as one group, amortizing dispatch
-  overhead (``microbatch=m``).  Results are unstacked at retirement, so the
+  overhead (``microbatch=m``).  At retirement a stacked group's outputs are
+  split into per-token rows by one compiled call (one executable per row
+  count and device, compiled by :meth:`PipelineExecutor.warmup`), so the
   API is token-in/token-out either way.
 * **Counters and spans** — per-stage issue counts and host-issue time,
   pool occupancy, the host time admission waited on a full pool
-  (``pool_wait_ms``), the time retirement spent unstacking groups
-  (``unstack_ms``), and the group rows dispatched and padded are tracked
+  (``pool_wait_ms``), the host time retirement spent splitting groups into
+  tokens (``unstack_ms``, the split's dispatch), the stacked groups split
+  (``groups_split``), and the group rows dispatched and padded are tracked
   continuously; :meth:`PipelineExecutor.stats` exposes them for the
   serving layer's metrics endpoint.  Admission and retirement are also
   ``jax.profiler.TraceAnnotation`` spans (``dispatch`` with children
@@ -186,7 +189,8 @@ class ExecutorStats:
     seam_joins: int = 0            # tokens admitted into in-flight groups
     seam_evictions: int = 0        # seats evicted before their group sealed
     pool_wait_ms: float = 0.0      # admission blocked on a full token pool
-    unstack_ms: float = 0.0        # retirement slicing groups into tokens
+    unstack_ms: float = 0.0        # retirement splitting groups into tokens
+    groups_split: int = 0          # stacked groups retired by the split
     rows_dispatched: int = 0       # group rows sent to the stages, padding in
     rows_padded: int = 0           # of those, rows that carry no token
     # failed stage calls per CONFIGURED device ordinal — the replanner's
@@ -214,6 +218,7 @@ class ExecutorStats:
             "seam_evictions": self.seam_evictions,
             "pool_wait_ms": round(self.pool_wait_ms, 4),
             "unstack_ms": round(self.unstack_ms, 4),
+            "groups_split": self.groups_split,
             "rows_dispatched": self.rows_dispatched,
             "rows_padded": self.rows_padded,
             "device_errors": {str(k): v
@@ -232,6 +237,16 @@ def _set_row(v: jax.Array, row: jax.Array, a: Any) -> jax.Array:
     shape, not one per seat (an eager ``.at[int]`` compiles per index, and
     the seam pays it under the executor lock)."""
     return v.at[row].set(a)
+
+
+@jax.jit
+def split_rows(out: Any) -> tuple:
+    """A stacked group's outputs (one array or a tuple) as a tuple of
+    per-row pytrees, each what ``out[i]`` gives: one launch per group and
+    one executable per row count and device, where eager indexing costs a
+    Python indexing call and two device programs per row."""
+    rows = jax.tree.leaves(out)[0].shape[0]
+    return tuple(jax.tree.map(lambda x: x[i], out) for i in range(rows))
 
 
 # --------------------------------------------------------------------------- #
@@ -870,7 +885,9 @@ class PipelineExecutor:
         r``, and each pinned replica's device builds its own jit
         executable, so one warm group per replica (``max(replicas)``
         consecutive seqs cover every stage's replicas) keeps first-touch
-        compiles off the serving path for devices 1..N-1 too.  The
+        compiles off the serving path for devices 1..N-1 too.  Every warm
+        group retires, so the retirement split compiles here as well, for
+        each stacked row count on each device the last stage runs on.  The
         attached profiler (if any) is suspended so compile time never
         lands in the profile and poisons the first re-plan decision."""
         prof, self.profiler = self.profiler, None
@@ -920,12 +937,15 @@ class PipelineExecutor:
                 t.join(timeout=30.0)
 
     def compile_count(self) -> int:
-        """Executables compiled across per-token and vmapped stage fns.
+        """Executables compiled across per-token and vmapped stage fns,
+        plus the retirement split's (shared by every executor in the
+        process: one per row count and device).
 
         Constant across identical-shape token waves after :meth:`warmup` —
         the zero-recompile steady-state invariant the serving layer asserts.
         """
         total = sum(getattr(f, "compiles", 0) for f in self.stage_fns)
+        total += split_rows._cache_size()
         if self._batched_fns is not None:
             for f in self._batched_fns:
                 try:
@@ -1422,7 +1442,16 @@ class PipelineExecutor:
             self._finalize(oldest)
 
     def _finalize(self, g: _Group) -> None:
-        """Block on a group's final outputs and unstack them.
+        """Block on a group's final outputs and split them into tokens.
+
+        A stacked group's device outputs are split by one compiled call
+        (:func:`split_rows`) over all ``g.rows`` rows, padding included,
+        so the row counts are the buckets :meth:`warmup` compiled; the
+        first ``g.size`` rows are the tokens' results.  Host (numpy)
+        outputs are indexed row by row, as a row is a view.  A
+        singleton's outputs are its token's result as they are.
+        ``unstack_ms`` times this step on the host (the split's dispatch,
+        not its device copy) inside the ``retire.unstack`` span.
 
         Idempotent; callable from any thread.  The executor lock is NOT
         held across the device wait — only the per-group lock serializes
@@ -1433,6 +1462,7 @@ class PipelineExecutor:
         """
         finalized_here = False
         unstack_ms = 0.0
+        split = False
         with TraceAnnotation("retire", group=g.gid, rows=g.rows), g.lock:
             if not g.done:
                 try:
@@ -1451,11 +1481,15 @@ class PipelineExecutor:
                                          rows=g.rows):
                         if not g.stacked:
                             g.results = [out]
-                        elif isinstance(out, tuple):
-                            g.results = [tuple(o[i] for o in out)
-                                         for i in range(g.size)]
+                        elif all(isinstance(x, jax.Array)
+                                 for x in jax.tree.leaves(out)):
+                            g.results = list(split_rows(out)[:g.size])
+                            split = True
                         else:
-                            g.results = [out[i] for i in range(g.size)]
+                            # host outputs (stages run outside jit): a
+                            # row is a view, with nothing to launch
+                            g.results = [jax.tree.map(lambda x: x[i], out)
+                                         for i in range(g.size)]
                     unstack_ms = (time.perf_counter() - t0) * 1e3
                 except BaseException as e:
                     # an execute-time failure (threaded stage, or a runtime
@@ -1470,6 +1504,7 @@ class PipelineExecutor:
             if finalized_here:           # exactly-once accounting per group
                 self._stats.tokens_retired += g.size
                 self._stats.unstack_ms += unstack_ms
+                self._stats.groups_split += split
                 if g.error is not None:
                     self._stats.tokens_failed += g.size
                 self._occupancy -= g.size

@@ -559,12 +559,15 @@ class BuiltPipeline:
         return self._batched_fns
 
     def compile_count(self) -> int:
-        """Total executables compiled across all stage fns (incl. vmapped).
+        """Total executables compiled across all stage fns (incl. vmapped)
+        and the executors' retirement split (one per row count and device,
+        shared by every executor in the process).
 
         Steady-state serving must hold this constant: after warmup, token
         waves of already-seen shapes re-enter cached executables only.
         """
-        total = 0
+        from .executor import split_rows
+        total = split_rows._cache_size()
         for f in self.stage_fns:
             total += getattr(f, "compiles", 0)
         if self._batched_fns is not None:

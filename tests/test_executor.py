@@ -1,4 +1,11 @@
 """Async PipelineExecutor + serving loop + pipeline bugfix regressions."""
+import contextlib
+import os
+import subprocess
+import sys
+import textwrap
+import threading
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -367,3 +374,252 @@ def test_elastic_planner_without_db_still_plans():
     assert planner.boundaries(2) == [0, 2]
     with pytest.raises(ValueError, match="ModuleDatabase"):
         planner.executor_for(2)
+
+
+# --------------------------------------------------------------------------- #
+# retirement: one compiled split a stacked group
+# --------------------------------------------------------------------------- #
+def _two_out(env):
+    return {"y": env["x"] * 2.0 + 1.0,
+            "n": (env["x"] > 2.0).astype(jnp.int32)}
+
+
+def _recorded_outputs(ex: PipelineExecutor) -> list:
+    """The outputs each group's retirement splits, in retirement order."""
+    seen = []
+    out_of = ex._out_of
+
+    def record(env):
+        seen.append(out_of(env))
+        return seen[-1]
+    ex._out_of = record
+    return seen
+
+
+def _assert_bitwise(got, want) -> None:
+    """Same pytree, shapes, dtypes, weak types and bytes as ``out[i]``."""
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert (a.shape, a.dtype, a.weak_type) == (b.shape, b.dtype,
+                                                   b.weak_type)
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+
+
+def _eager_rows(out, size: int) -> list:
+    """Retirement's per-row indexing before the compiled split."""
+    return [jax.tree.map(lambda x: x[i], out) for i in range(size)]
+
+
+@pytest.mark.parametrize("outputs", [["y"], ["y", "n"]],
+                         ids=["array", "tuple"])
+@pytest.mark.parametrize("size, buckets, rows", [
+    (4, None, 4), (2, None, 4), (3, None, 4),
+    (2, (1, 2, 4), 2), (3, (1, 2, 4), 4)])
+def test_split_matches_eager_indexing(outputs, size, buckets, rows):
+    ex = PipelineExecutor([jax.jit(_two_out)], ["x"], outputs,
+                          max_in_flight=4, microbatch=4,
+                          pad_microbatches=True, buckets=buckets)
+    seen = _recorded_outputs(ex)
+    toks = [jnp.arange(6.0).reshape(2, 3) + i for i in range(size)]
+    got = ex.run(toks)
+    (out,) = seen
+    assert jax.tree.leaves(out)[0].shape[0] == rows
+    assert len(got) == size
+    for g, w in zip(got, _eager_rows(out, size)):
+        _assert_bitwise(g, w)
+    st = ex.stats()
+    assert (st.groups_admitted, st.groups_split) == (1, 1)
+
+
+@pytest.mark.parametrize("outputs", [["y"], ["y", "n"]],
+                         ids=["array", "tuple"])
+def test_a_singleton_result_is_the_unsplit_output(outputs):
+    ex = PipelineExecutor([jax.jit(_two_out)], ["x"], outputs,
+                          max_in_flight=4, microbatch=4,
+                          pad_microbatches=True)
+    seen = _recorded_outputs(ex)
+    got = ex.submit(jnp.ones((2, 3))).result()
+    assert got is seen[0]
+    assert ex.stats().groups_split == 0
+
+
+def test_groups_split_counts_stacked_groups_only():
+    ex = PipelineExecutor([jax.jit(_two_out)], ["x"], ["y"],
+                          max_in_flight=4, microbatch=4,
+                          pad_microbatches=True, buckets=(1, 2, 4))
+    for n in (1, 2, 1, 3, 4, 1):
+        ex.run([jnp.full((2, 3), float(n))] * n)
+    st = ex.stats()
+    assert (st.groups_admitted, st.groups_split) == (6, 3)
+    assert st.as_dict()["groups_split"] == 3
+
+
+def test_host_outputs_are_indexed_not_split():
+    def host(env):
+        return {"y": np.asarray(env["x"], np.float64) * 2.0}
+    ex = PipelineExecutor([host], ["x"], ["y"], max_in_flight=4,
+                          microbatch=4, pad_microbatches=True,
+                          batched_fns=[host])
+    seen = _recorded_outputs(ex)
+    got = ex.run([np.full((3,), float(i)) for i in range(3)])
+    for g, w in zip(got, _eager_rows(seen[0], 3)):
+        assert isinstance(g, np.ndarray) and g.dtype == np.float64
+        assert g.tobytes() == w.tobytes()
+    assert ex.stats().groups_split == 0
+
+
+def test_split_serves_open_group_joins():
+    """Joined seats retire with their group through the split; the stage-0
+    worker is parked inside an older group so the seam stays open."""
+    gate, entered = threading.Event(), threading.Event()
+    first = [True]
+
+    def pre(env):
+        if first[0]:
+            first[0] = False
+            entered.set()
+            assert gate.wait(timeout=10.0)
+        return {"x": env["x"] + 1.0}
+
+    def post(env):
+        return {"y": env["x"] * 3.0}
+
+    fns = [pre, post]
+    ex = PipelineExecutor(fns, ["x"], ["y"], max_in_flight=16,
+                          replicas=[1, 1], microbatch=4,
+                          pad_microbatches=True, buckets=(4,),
+                          batched_fns=fns, open_groups=True)
+    seen = _recorded_outputs(ex)
+    try:
+        blocker = ex.submit(jnp.zeros(3))
+        assert entered.wait(5.0)
+        hB = ex.submit(jnp.full((3,), 1.0))
+        joins = [ex.try_join((jnp.full((3,), float(i)),)) for i in (2, 3)]
+        assert all(j is not None for j in joins)
+        gate.set()
+        blocker.result()
+        got = [h.result() for h in [hB] + joins]
+    finally:
+        gate.set()
+        ex.close()
+    assert len(seen) == 2
+    for g, w in zip(got, _eager_rows(seen[1], 3)):
+        _assert_bitwise(g, w)
+    np.testing.assert_array_equal(np.asarray(got[2]), np.full(3, 12.0))
+    st = ex.stats()
+    assert (st.seam_joins, st.groups_split) == (2, 2)
+
+
+@contextlib.contextmanager
+def _backend_compiles():
+    """Times XLA compiled a program while the block ran."""
+    seen: list[str] = []
+
+    def on_event(event, duration_secs, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            seen.append(event)
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        yield seen
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+
+
+def _scale(env):
+    return {"x": env["x"] * 0.5}
+
+
+@pytest.mark.parametrize("mode, width", [
+    ({}, 7), ({"stage_workers": True}, 8), ({"replicas": [1, 2]}, 9)],
+    ids=["async", "threaded", "replicated"])
+@pytest.mark.parametrize("buckets", [None, (1, 2, 4)],
+                         ids=["pad-to-max", "buckets"])
+def test_warmup_compiles_every_split_a_wave_needs(mode, width, buckets):
+    ex = PipelineExecutor([jax.jit(_scale), jax.jit(_two_out)], ["x"],
+                          ["y", "n"], max_in_flight=8, microbatch=4,
+                          pad_microbatches=True, buckets=buckets, **mode)
+    # a shape no other case compiles: the split's executables are shared
+    # by every executor in the process
+    tok = jnp.ones((2, width + (10 if buckets else 0)))
+    ex.warmup(tok)
+    c0 = ex.compile_count()
+    with _backend_compiles() as compiles:
+        for n in range(1, 5):
+            ex.run([tok] * n)
+        ex.run([tok] * 10)                      # groups of 4, 4 and 2
+    ex.close()
+    assert ex.compile_count() == c0 and compiles == []
+    assert ex.stats().groups_split == 3 + 3
+
+
+FOUR_DEVICES = textwrap.dedent("""
+    import sys
+    import jax, jax.numpy as jnp
+    import numpy as np
+    from repro.core import DeviceInventory
+    from repro.core.executor import PipelineExecutor
+
+    buckets = eval(sys.argv[1])
+    inv = DeviceInventory.detect()
+    assert len(inv) == 4, jax.devices()
+    compiles = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, secs, **kw: compiles.append(event)
+        if event == "/jax/core/compile/backend_compile_duration" else None)
+
+    def head(env):
+        return {"x": env["x"] * 2.0 + 1.0}
+
+    def tail(env):
+        return {"y": env["x"] - 0.5, "n": (env["x"] > 4.0).astype(jnp.int32)}
+
+    # widened: each stage on two replicas, the last on chips 2 and 3
+    ex = PipelineExecutor([jax.jit(head), jax.jit(tail)], ["x"], ["y", "n"],
+                          replicas=[2, 2], devices=[[0, 1], [2, 3]],
+                          inventory=inv, max_in_flight=8, microbatch=4,
+                          pad_microbatches=True, buckets=buckets)
+    sizes = [1, 2, 3, 4, 3, 2, 1, 4]
+    toks = [jnp.full((2, 3), float(i)) for i in range(len(sizes))]
+    ex.warmup(toks[0])
+    c0, n0 = ex.compile_count(), len(compiles)
+    seen = []
+    out_of = ex._out_of
+    ex._out_of = lambda env: seen.append(out_of(env)) or seen[-1]
+    got = [ex.run([t] * n) for t, n in zip(toks, sizes)]
+    ex.close()
+    assert ex.compile_count() == c0, (ex.compile_count(), c0)
+    assert len(compiles) == n0, compiles[n0:]
+    assert ex.stats().out_of_order_retired == 0
+    assert ex.stats().groups_split == sum(n > 1 for n in sizes)
+    for n, rows, out in zip(sizes, got, seen):
+        (dev,) = out[0].devices()
+        assert dev in (inv.jax_device(2), inv.jax_device(3)), dev
+        if n == 1:
+            assert rows[0] is out
+            continue
+        for i, r in enumerate(rows):
+            for a, b in zip(r, out):
+                assert a.devices() == {dev}
+                assert a.dtype == b.dtype and a.shape == b.shape[1:]
+                assert np.asarray(a).tobytes() == np.asarray(b[i]).tobytes()
+    print("SPLIT-4-OK", sorted({d.id for o in seen for d in o[0].devices()}))
+""")
+
+
+@pytest.mark.parametrize("buckets", [None, (1, 2, 4)],
+                         ids=["pad-to-max", "buckets"])
+def test_split_on_four_devices_with_a_widened_plan(buckets):
+    """The split runs where the last stage's replica left the group, and
+    warmup compiles it on each of their devices."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
+                        + " --xla_force_host_platform_device_count=4")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(root, "src")]
+        + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
+    r = subprocess.run([sys.executable, "-c", FOUR_DEVICES, repr(buckets)],
+                       capture_output=True, text=True, timeout=300.0,
+                       env=env, cwd=root)
+    assert r.returncode == 0, r.stdout + r.stderr
+    assert "SPLIT-4-OK [2, 3]" in r.stdout, r.stdout

@@ -28,8 +28,8 @@ from chipbench import run as bench  # noqa: E402
 from chipbench import trace_reduce  # noqa: E402
 from chipbench.record import RunData  # noqa: E402
 
-NEW_COUNTERS = ("pool_wait_ms", "unstack_ms", "rows_dispatched",
-                "rows_padded")
+NEW_COUNTERS = ("pool_wait_ms", "unstack_ms", "groups_split",
+                "rows_dispatched", "rows_padded")
 
 
 def _double(env):
