@@ -472,7 +472,7 @@ def _rule_dead_export(targets: Sequence[LintContext],
                       refs: Sequence[LintContext]) -> Iterable[Diagnostic]:
     """A public module-level def that nothing *uses* drifts silently (the
     ``spmd_pipeline`` failure mode).  Use = a Name/Attribute reference or an
-    ``from x import name`` anywhere across src/tests/benchmarks/examples —
+    ``from x import name`` anywhere across src/tests/examples —
     in the def's own module, only references *outside the def itself* count
     (a def is not kept alive by its own body or recursion alone, but a
     helper its module genuinely calls is).  Re-exports from ``__init__.py``
@@ -578,9 +578,9 @@ def lint_paths(paths: Sequence[str], *,
 
     ``ref_roots`` are the directories scanned for *references* by project
     rules (dead-export).  By default they are derived from the first
-    target path: the sibling ``src``/``tests``/``benchmarks``/``examples``
-    directories of the enclosing repo, so ``lint_paths(["src/repro"])``
-    counts a use in ``tests/`` or ``benchmarks/``.
+    target path: the sibling ``src``/``tests``/``examples`` directories of
+    the enclosing repo, so ``lint_paths(["src/repro"])`` counts a use in
+    ``tests/`` or ``examples/``.
     """
     files = [f for p in paths for f in _py_files(p)]
     targets = _load(files)
@@ -591,7 +591,7 @@ def lint_paths(paths: Sequence[str], *,
                 break
             root = os.path.dirname(root)
         ref_roots = [os.path.join(root, d)
-                     for d in ("src", "tests", "benchmarks", "examples")
+                     for d in ("src", "tests", "examples")
                      if os.path.isdir(os.path.join(root, d))]
     ref_files = {os.path.abspath(f)
                  for r in ref_roots for f in _py_files(r)}

@@ -15,7 +15,7 @@ from .costmodel import (CostModel, DeviceClass, DEVICE_CLASSES,
                         HBM_BW, HOST_XFER_BW, ICI_BW_PER_LINK, HBM_BYTES,
                         PROFILE_MARGIN, VMEM_BYTES, attention_cost,
                         device_class, elementwise_cost, fused_cost,
-                        matmul_cost, measure_ms, measured_contradicts,
+                        matmul_cost, measured_contradicts,
                         replicated_bottleneck_ms, stencil_cost, transfer_ms)
 from .database import ModuleDatabase, ModuleEntry, default_db
 from .executor import (ExecutorClosed, ExecutorStats, PendingToken,
@@ -43,7 +43,7 @@ __all__ = [
     "NodeCost", "PEAK_FLOPS_BF16", "HBM_BW", "HOST_XFER_BW",
     "ICI_BW_PER_LINK", "HBM_BYTES", "PROFILE_MARGIN", "VMEM_BYTES",
     "attention_cost", "device_class", "elementwise_cost", "fused_cost",
-    "matmul_cost", "measure_ms", "measured_contradicts",
+    "matmul_cost", "measured_contradicts",
     "replicated_bottleneck_ms", "stencil_cost", "transfer_ms",
     "ModuleDatabase", "ModuleEntry", "default_db",
     "ExecutorClosed", "ExecutorStats", "PendingToken", "PipelineExecutor",

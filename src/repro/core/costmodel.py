@@ -18,7 +18,6 @@ HW times are mixed in one table.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Callable, Sequence
 
@@ -34,8 +33,8 @@ HBM_BYTES = 16 * 1024**3        # 16 GiB HBM per chip
 # Scoped VMEM every Pallas kernel here is compiled with
 # (``repro.kernels.backend.compiler_params``); the fusion model, the plan
 # verifier and the fused kernel's row-block search check working sets
-# against the same figure.  A v5e core has 128 MiB of VMEM; Mosaic's default scoped
-# limit is 16 MiB, too small for cvtColor's 3-on-lanes block at 1920 wide.
+# against the same figure.  A v5e core has 128 MiB of VMEM; Mosaic's default
+# scoped limit is 16 MiB.
 VMEM_BYTES = 64 * 1024**2
 MXU_TILE = (128, 128)           # systolic array tile
 LANE = 128                      # vector lane width
@@ -348,25 +347,6 @@ def measured_contradicts(model_ms: float | None, measured_ms: float | None,
         return measured_ms > 0.0
     ratio = measured_ms / model_ms
     return ratio >= margin or ratio <= 1.0 / margin
-
-
-# --------------------------------------------------------------------------- #
-# Measured profiles (the Frontend's profile log)
-# --------------------------------------------------------------------------- #
-def measure_ms(fn: Callable, *args, warmup: int = 1, iters: int = 3) -> float:
-    """Wall-clock a callable (blocks on JAX async dispatch via block_until_ready)."""
-    import jax
-
-    def _run():
-        out = fn(*args)
-        return jax.block_until_ready(out)
-
-    for _ in range(warmup):
-        _run()
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        _run()
-    return (time.perf_counter() - t0) / iters * 1e3
 
 
 @dataclass

@@ -1,25 +1,43 @@
-"""Asynchronous token-pipeline executor (TBB ``parallel_pipeline`` analog).
+"""Token-pipeline executor (TBB ``parallel_pipeline`` analog).
 
-:class:`BuiltPipeline.run` emulates TBB's token pipeline with a *synchronous
-wavefront*: a Python loop that advances every in-flight token by one stage
-per host step.  That keeps tokens ordered but serializes the host around the
-wavefront schedule.  This module replaces it with a true asynchronous
-executor that leans on JAX's async dispatch the way TBB leans on its thread
-pool:
+:class:`PipelineExecutor` runs a token stream through a pipeline's compiled
+stage functions, the way TBB runs tokens through its filters.  One
+admission path, one token pool and one in-order retirement path serve two
+ways of running the stages:
 
-* **Eager issue** — when a token is admitted, *all* of its stage calls are
-  issued immediately.  Each jitted stage returns future-backed arrays, so
-  stage ``s+1`` is enqueued on the device stream as soon as stage ``s``'s
-  output futures exist; the host never blocks between stages.  Work for
-  token ``k+1`` is therefore issued while token ``k`` is still executing —
-  the paper's "Task #0 can take the second input while Task #1 is
-  processing".
+* **Async issue** (the default; every served configuration runs it) —
+  when a group is admitted, *all* of its stage calls are issued at once.
+  Each jitted stage returns future-backed arrays, so stage ``s+1`` is
+  enqueued on the device stream as soon as stage ``s``'s output futures
+  exist; the host never blocks between stages.  Work for token ``k+1`` is
+  therefore issued while token ``k`` is still executing — the paper's
+  "Task #0 can take the second input while Task #1 is processing".  JAX's
+  async dispatch stands in for TBB's thread pool.
+* **Rings** (``replicas=[r0, r1, ...]``) — stage ``s`` runs ``r_s``
+  worker threads, each running its stage to completion and handing the
+  group on.  All-ones ``replicas`` is TBB's serial threaded model: stages
+  overlap across threads, which is what host-bound stages (callbacks,
+  eager sw fallbacks) need, since async dispatch gives those no overlap.
+  Wider entries are TBB's *parallel* filters: a stage that dominates the
+  token period is *widened* instead of only re-balanced.  Admitted groups
+  get a monotonically increasing sequence number; replica ``w`` of a stage
+  with ``r`` replicas owns the seqs ``w, w+r, w+2r, ...`` and consumes
+  them in that order from its ring (each seq has exactly one producer —
+  the upstream worker that finished it — so slots are
+  single-producer/single-consumer and the hand-off is one flag flip, not a
+  queue mutation).  Envs ride through the stages on the group object and
+  are handed off with no retained references, so
+  :class:`~repro.core.pipeline.StageFn` buffer donation stays safe.
+
+Common to both:
+
 * **Bounded token pool** — at most ``max_in_flight`` tokens are
   issued-but-unretired at any moment (TBB's token pool; default
-  ``n_stages + 1``, the double-buffering minimum).  Admission blocks on the
-  *oldest* token's final outputs when the pool is full, which is also the
-  serving layer's backpressure mechanism.  ``max_in_flight`` must be >= 1;
-  ``0`` is rejected rather than silently treated as "unset".
+  ``n_stages + 1``, the double-buffering minimum, or ``sum(replicas) + 1``
+  with rings).  Admission blocks on the *oldest* token's final outputs
+  when the pool is full, which is also the serving layer's backpressure
+  mechanism.  ``max_in_flight`` must be >= 1; ``0`` is rejected rather
+  than silently treated as "unset".
 * **Per-stage micro-batching** — consecutive tokens whose input
   shapes/dtypes agree can be stacked along a new leading axis and pushed
   through ``jax.vmap``-ed stage functions as one group, amortizing dispatch
@@ -27,6 +45,12 @@ pool:
   split into per-token rows by one compiled call (one executable per row
   count and device, compiled by :meth:`PipelineExecutor.warmup`), so the
   API is token-in/token-out either way.
+* **In-order retirement** — tokens retire oldest-first, matching the
+  paper's ``serial_in_order`` first/last filters.  With rings the
+  in-order ``_inflight`` deque and each group's completion event are the
+  reorder buffer, so tokens retire in submission order even when replicas
+  finish out of order; ``ExecutorStats.out_of_order_retired`` asserts it
+  stayed zero.
 * **Counters and spans** — per-stage issue counts and host-issue time,
   pool occupancy, the host time admission waited on a full pool
   (``pool_wait_ms``), the host time retirement spent splitting groups into
@@ -41,31 +65,12 @@ pool:
   microsecond each and are written only while a profile is recording.
 * **Online profiling** — an attached
   :class:`~repro.core.profiler.StageProfiler` is fed measured per-stage
-  wall times: exactly in threaded mode, by sampled blocking barriers in
-  async mode (every ``profiler.sample_every``-th group), so the adaptive
-  re-planner always has live costs without stalling steady-state traffic.
-* **Threaded stage workers** (``stage_workers=True``) — one serial worker
-  thread per stage, TBB's actual execution model.  Each admitted group's
-  stage ``s`` runs to completion inside worker ``s`` and hands its env to
-  worker ``s+1``; host-bound stages (callbacks, eager sw fallbacks) then
-  overlap across *threads* instead of relying on device async dispatch,
-  which on CPU backends provides no inter-stage overlap at all.
-* **Replicated stages** (``replicas=[r0, r1, ...]``) — TBB's *parallel*
-  filter kind: stage ``s`` runs ``r_s`` worker threads, so a stage that
-  dominates the token period can be *widened* instead of only re-balanced.
-  The dataflow is a sequence-numbered ring per replica: admitted groups
-  get a monotonically increasing sequence number; replica ``w`` of a stage
-  with ``r`` replicas owns the seqs ``w, w+r, w+2r, ...`` and consumes
-  them in that order from a preallocated slot ring (each seq has exactly
-  one producer — the upstream worker that finished it — so slots are
-  single-producer/single-consumer and the hand-off cost is one flag flip,
-  not a queue mutation).  Envs ride through the stages unmodified (no
-  per-group dict rebuilds on the steady path) and are handed off with no
-  retained references, so :class:`~repro.core.pipeline.StageFn` buffer
-  donation stays safe.  A reorder buffer at retirement — the in-order
-  ``_inflight`` deque plus each group's completion event — guarantees
-  tokens retire in submission order even when replicas finish out of
-  order; ``ExecutorStats.out_of_order_retired`` asserts it stayed zero.
+  wall times: every stage call on the rings, by sampled blocking barriers
+  under async issue (every ``profiler.sample_every``-th group), so the
+  adaptive re-planner always has live costs without stalling steady-state
+  traffic.
+
+Rings alone:
 
 * **Replica quarantine + bounded retry** — a stage exception on a
   *replicated* stage no longer errors the group.  The failing worker
@@ -87,9 +92,8 @@ pool:
   half-donated buffer (a real mid-execution failure that already donated
   its buffers will surface on the retry and error the group — degraded,
   not wrong).
-
-Completion is in-order (tokens retire oldest-first), matching the paper's
-``serial_in_order`` first/last filters.
+* **Device pinning** (``devices=``) and **continuous batching**
+  (``open_groups=``) — see the class parameters.
 """
 from __future__ import annotations
 
@@ -97,7 +101,6 @@ import itertools
 import threading
 import time
 from collections import deque
-from concurrent.futures import Future, ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Any, Callable, Iterable, Sequence
 
@@ -262,8 +265,7 @@ def _sig_of(args: tuple) -> tuple:
 
     Arrays (jax/numpy) expose ``shape``/``dtype`` as cached attributes —
     reading them is orders of magnitude cheaper than ``jnp.shape`` +
-    ``jnp.result_type``, which the admit loop previously paid per arg per
-    token (the dominant per-token overhead of async mode vs the wavefront).
+    ``jnp.result_type``, a cost the admit loop would pay per arg per token.
     """
     sig = []
     for a in args:
@@ -287,7 +289,7 @@ class _Group:
     """One admitted token group: a (possibly stacked) env fully issued."""
 
     __slots__ = ("env", "size", "stacked", "results", "done", "error", "lock",
-                 "future", "seq", "fns", "evt", "retries", "t_admit",
+                 "seq", "fns", "evt", "retries", "t_admit",
                  "sealed", "rows", "sig", "evicted", "gid")
 
     def __init__(self, env: dict | None, size: int, stacked: bool,
@@ -300,10 +302,9 @@ class _Group:
         self.done = False
         self.error: BaseException | None = None   # stage issue failed
         self.lock = threading.Lock()  # serializes issue + finalization
-        self.future: Future | None = None  # last-stage future (threaded mode)
-        self.seq: int | None = None   # admission sequence (replicated mode)
-        self.fns: tuple | None = None  # resolved stage fns (replicated mode)
-        self.evt: threading.Event | None = None  # completion (replicated mode)
+        self.seq: int | None = None   # admission sequence (rings)
+        self.fns: tuple | None = None  # resolved stage fns (rings)
+        self.evt: threading.Event | None = None  # completion (rings)
         self.retries = 0              # failed stage calls re-executed
         self.t_admit = time.perf_counter()  # retry_budget_ms anchor
         # --- continuous-batching seam state (open_groups mode) ---
@@ -459,25 +460,19 @@ class PipelineExecutor:
         executor builds its own lazily.
     profiler:
         Optional :class:`~repro.core.profiler.StageProfiler` fed measured
-        per-stage wall times (every stage call in threaded mode; every
-        ``profiler.sample_every``-th group via a blocking barrier in async
-        mode).  ``warmup`` suspends it so compile time never pollutes the
-        profile.
-    stage_workers:
-        Run each stage in its own serial worker thread (the TBB execution
-        model): stage ``s+1`` of a group starts when stage ``s`` finished,
-        and different stages overlap across OS threads.  Use for pipelines
-        whose stage time is host-bound (eager sw fallbacks, callbacks) —
-        JAX async dispatch alone gives those zero overlap on CPU.
+        per-stage wall times (every stage call on the rings; every
+        ``profiler.sample_every``-th group via a blocking barrier under
+        async issue).  ``warmup`` suspends it so compile time never
+        pollutes the profile.
     replicas:
-        Per-stage worker counts (TBB's *parallel* filters): stage ``s``
-        runs on ``replicas[s]`` threads fed by sequence-numbered
-        SPSC-per-replica rings, with a reorder buffer guaranteeing
-        in-order retirement (see module docstring).  Implies the threaded
-        execution model; ``stage_workers`` is ignored when given.  Use
+        Per-stage worker counts: stage ``s`` runs on ``replicas[s]``
+        threads fed by sequence-numbered SPSC-per-replica rings, with a
+        reorder buffer guaranteeing in-order retirement (see module
+        docstring).  All-ones is the serial threaded model (TBB's serial
+        filters: stages overlap across OS threads, for host-bound stages);
+        wider entries are TBB's *parallel* filters.  Use
         :func:`repro.core.partition.assign_replicas` to pick the factors
-        from measured stage costs.  All-ones is the serial threaded model
-        on the ring dataflow.
+        from measured stage costs.  ``None`` runs async issue.
     devices:
         Per-stage per-replica device ordinals (the planner's
         :meth:`~repro.core.partition.PipelinePlan.stage_devices`): replica
@@ -496,7 +491,7 @@ class PipelineExecutor:
         ``DeviceInventory.detect()`` when ``devices`` is given.
     fault_injector:
         Optional :class:`~repro.runtime.faults.FaultInjector` called in
-        front of every stage body (all execution modes).  Injected faults
+        front of every stage body (async issue and rings).  Injected faults
         take the same recovery path as real stage exceptions.
     max_group_retries:
         Retry budget per group across all stages (replicated mode only):
@@ -539,7 +534,7 @@ class PipelineExecutor:
                  pad_microbatches: bool = False,
                  buckets: Sequence[int] | None = None,
                  batched_fns: Sequence[Callable] | None = None,
-                 profiler: Any = None, stage_workers: bool = False,
+                 profiler: Any = None,
                  replicas: Sequence[int] | None = None,
                  devices: Sequence[Sequence[int]] | None = None,
                  inventory: Any = None, fault_injector: Any = None,
@@ -611,15 +606,6 @@ class PipelineExecutor:
         self._batched_fns: list[Callable] | None = (
             list(batched_fns) if batched_fns is not None else None)
         self.profiler = profiler
-        self.stage_workers = bool(stage_workers) and self.replicas is None
-        self._pools: list[ThreadPoolExecutor] | None = None
-        if self.stage_workers:
-            # one SERIAL worker per stage: per-stage ordering is preserved
-            # (TBB's serial filters) while distinct stages run concurrently
-            self._pools = [
-                ThreadPoolExecutor(max_workers=1,
-                                   thread_name_prefix=f"stage-{i}")
-                for i in range(len(self.stage_fns))]
         if max_group_retries < 0:
             raise ValueError(
                 f"max_group_retries must be >= 0 (got {max_group_retries})")
@@ -695,7 +681,7 @@ class PipelineExecutor:
                       microbatch: int = 1,
                       pad_microbatches: bool = False,
                       buckets: Sequence[int] | None = None,
-                      profiler: Any = None, stage_workers: bool = False,
+                      profiler: Any = None,
                       replicas: Sequence[int] | None = None,
                       devices: Sequence[Sequence[int]] | None = None,
                       inventory: Any = None, fault_injector: Any = None,
@@ -715,8 +701,7 @@ class PipelineExecutor:
         return cls(pipe.stage_fns, pipe.graph_inputs, pipe.graph_outputs,
                    max_in_flight=mif, microbatch=microbatch,
                    pad_microbatches=pad_microbatches, buckets=buckets,
-                   batched_fns=batched, profiler=profiler,
-                   stage_workers=stage_workers, replicas=replicas,
+                   batched_fns=batched, profiler=profiler, replicas=replicas,
                    devices=devices, inventory=inventory,
                    fault_injector=fault_injector,
                    max_group_retries=max_group_retries,
@@ -913,7 +898,7 @@ class PipelineExecutor:
         self.reset_stats()
 
     def close(self) -> None:
-        """Drain in-flight work and shut down stage-worker threads.
+        """Drain in-flight work and shut down the ring worker threads.
 
         Sets ``closed`` so caches (e.g. ElasticPlanner's) never hand a
         shut-down executor back out.  ``closed`` is published under the
@@ -926,9 +911,6 @@ class PipelineExecutor:
         with self._lock:
             self.closed = True
         self.drain()
-        if self._pools is not None:
-            for p in self._pools:
-                p.shutdown(wait=True)
         if self._rings is not None:
             for stage_rings in self._rings:
                 for ring in stage_rings:
@@ -1169,11 +1151,6 @@ class PipelineExecutor:
             self._route(0, g.seq, g)
             enq = (time.perf_counter() - t0) * 1e3 / max(len(fns), 1)
             return [(si, enq) for si in range(len(fns))]
-        if self._pools is not None:
-            t0 = time.perf_counter()
-            self._issue_threaded(g, env, fns)
-            enq = (time.perf_counter() - t0) * 1e3 / max(len(fns), 1)
-            return [(si, enq) for si in range(len(fns))]
         # async-dispatch issue; sampled groups pay a blocking barrier per
         # stage so the profiler sees real wall times
         counters = []
@@ -1405,33 +1382,6 @@ class PipelineExecutor:
         with self._lock:
             return [sum(h) for h in self._healthy]
 
-    def _issue_threaded(self, g: _Group, env: dict,
-                        fns: Sequence[Callable]) -> None:
-        """Chain the group's stages across the serial per-stage workers.
-
-        Stage ``s``'s task waits on stage ``s-1``'s future, runs the stage
-        to completion (blocking on its device work), and returns the next
-        env.  Submission order per pool preserves per-stage token order.
-        """
-        prev: Future | None = None
-        for si, (fn, pool) in enumerate(zip(fns, self._pools)):
-            prev = pool.submit(self._run_stage, fn, si,
-                               env if prev is None else None, prev)
-        g.future = prev
-
-    def _run_stage(self, fn: Callable, si: int, env0: dict | None,
-                   prev: Future | None) -> dict:
-        env = env0 if prev is None else prev.result()
-        if self._injector is not None:
-            # non-replicated stage: an injected fault errors the group
-            # (no sibling to retry on), same as a real stage exception
-            self._injector.on_stage_call(si)
-        t0 = time.perf_counter()
-        out = jax.block_until_ready(fn(env))
-        if self.profiler is not None:
-            self.profiler.record(si, (time.perf_counter() - t0) * 1e3)
-        return out
-
     def _retire_through(self, group: _Group) -> None:
         """Finalize ``group`` and everything older (in-order retirement)."""
         while not group.done:
@@ -1468,12 +1418,10 @@ class PipelineExecutor:
                 try:
                     with TraceAnnotation("retire.wait", group=g.gid,
                                          rows=g.rows):
-                        if g.evt is not None:     # replicated stage workers
+                        if g.evt is not None:     # ring workers
                             g.evt.wait()
                             if g.error is not None:
                                 raise g.error
-                        elif g.future is not None:  # threaded stage workers
-                            g.env = g.future.result()
                         out = self._out_of(g.env)
                         jax.block_until_ready(out)
                     t0 = time.perf_counter()
@@ -1492,7 +1440,7 @@ class PipelineExecutor:
                                          for i in range(g.size)]
                     unstack_ms = (time.perf_counter() - t0) * 1e3
                 except BaseException as e:
-                    # an execute-time failure (threaded stage, or a runtime
+                    # an execute-time failure (a ring stage, or a runtime
                     # error surfacing at the blocking wait): the group still
                     # leaves the pipeline — it counts as retired so
                     # issued == retired holds and the pool slot is freed —

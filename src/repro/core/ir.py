@@ -270,7 +270,7 @@ def linear_ir(name: str, fn_keys: Sequence[str], times_ms: Sequence[float],
               io_shape: Sequence[int] = (1,), dtype: str = "float32") -> CourierIR:
     """Convenience builder: a linear chain IR from (fn_key, time) pairs.
 
-    Used by tests/benchmarks to replay the *paper's own profile* (Table I)
+    Used by tests to replay the *paper's own profile* (Table I)
     through the Pipeline Generator.
     """
     assert len(fn_keys) == len(times_ms)

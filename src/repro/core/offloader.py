@@ -97,21 +97,15 @@ class OffloadedFunction:
             self.fallbacks.append(f"pipeline: {type(e).__name__}: {e}")
             return self.original(*args)
 
-    def map(self, tokens: Iterable[Any]) -> list[Any]:
-        """Pipelined execution over a token stream (the deployed fast path)."""
-        if self.mode == "original":
-            return [self.original(*(t if isinstance(t, tuple) else (t,)))
-                    for t in tokens]
-        return self.pipeline.run(tokens)
+    def map(self, tokens: Iterable[Any], *,
+            max_in_flight: int | None = None,
+            microbatch: int = 1) -> list[Any]:
+        """Pipelined execution over a token stream (the deployed fast path).
 
-    def map_async(self, tokens: Iterable[Any], *,
-                  max_in_flight: int | None = None,
-                  microbatch: int = 1) -> list[Any]:
-        """Token stream through the asynchronous executor (serving path).
-
-        Same results/order as :meth:`map`, but stages are issued eagerly
-        with a bounded token pool and optional per-stage micro-batching
-        (see :class:`repro.core.executor.PipelineExecutor`).
+        Tokens go through the asynchronous executor: stages are issued
+        eagerly with a bounded token pool and optional per-stage
+        micro-batching (see :class:`repro.core.executor.PipelineExecutor`),
+        and results come back in submission order.
         """
         # validate before the mode branch so a bad serving config fails
         # deterministically, not only after a switch to "pipeline" mode
@@ -120,7 +114,8 @@ class OffloadedFunction:
         if microbatch < 1:
             raise ValueError(f"microbatch must be >= 1, got {microbatch}")
         if self.mode == "original":
-            return self.map(tokens)
+            return [self.original(*(t if isinstance(t, tuple) else (t,)))
+                    for t in tokens]
         return self.pipeline.run_async(tokens, max_in_flight=max_in_flight,
                                        microbatch=microbatch)
 

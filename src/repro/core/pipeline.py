@@ -11,30 +11,25 @@ Given a traced CourierIR and the module database, the generator
 4. emits one jitted callable per stage operating on the live-value
    environment at the stage boundary (the paper's "intermediate data ...
    stored in the external memory" — here, stage-boundary arrays in HBM),
-5. wraps everything in a :class:`BuiltPipeline` whose ``run`` executes a
-   TBB-style token pipeline: a wavefront schedule with a bounded number of
-   in-flight tokens (TBB's token pool), first/last stages serial-in-order.
+5. wraps everything in a :class:`BuiltPipeline`.
 
-JAX's async dispatch provides the overlap TBB gets from its thread pool:
-each stage call on a token returns immediately with futures, so stage s can
-be issued for token k+1 while token k is still executing downstream — the
-paper's "Task #0 can take the second input while Task #1 is processing".
+A :class:`BuiltPipeline` runs tokens two ways:
 
-Two token-stream execution paths are exposed:
-
-* ``BuiltPipeline.run``       — the original synchronous wavefront schedule
-  (host steps every in-flight token one stage at a time); kept as the
-  paper-faithful baseline.
-* ``BuiltPipeline.run_async`` / ``BuiltPipeline.executor()`` — the true
-  asynchronous executor (:mod:`repro.core.executor`): eager stage issue,
-  bounded token pool, optional per-stage micro-batching, issue, wait and
-  occupancy counters.  This is the serving-layer fast path.
+* ``BuiltPipeline(*args)`` / ``run_sequential`` — one token at a time
+  through every stage, no pipelining: the reference semantics.
+* ``BuiltPipeline.executor()`` / ``run_async`` — the token pipeline
+  (:mod:`repro.core.executor`): a bounded token pool (TBB's), in-order
+  retirement (the paper's serial-in-order first/last stages), eager
+  async stage issue or threaded rings, optional per-stage micro-batching,
+  and counters.  JAX's async dispatch provides the overlap TBB gets from
+  its thread pool: stage s is issued for token k+1 while token k is still
+  executing downstream — the paper's "Task #0 can take the second input
+  while Task #1 is processing".  This is the serving path.
 """
 from __future__ import annotations
 
 import re
 import warnings
-from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Callable, Iterable, Sequence
 
@@ -445,40 +440,6 @@ class BuiltPipeline:
             env = fn(env)
         return self._out_of(env)
 
-    # -- token pipeline (paper Fig. 2) -------------------------------------- #
-    def run(self, tokens: Iterable[tuple | Any]) -> list[Any]:
-        """Wavefront token pipeline with a bounded token pool.
-
-        Issues stage s for token k at wavefront step s+k; with JAX async
-        dispatch, issued stages overlap exactly like TBB's thread pool.
-        ``max_in_flight`` bounds live tokens (default: n_stages + 1, the
-        double-buffering minimum).
-        """
-        toks = [t if isinstance(t, tuple) else (t,) for t in tokens]
-        n = len(toks)
-        S = len(self.stage_fns)
-        pool = self._validated_pool()
-        envs: dict[int, Any] = {}
-        done: dict[int, Any] = {}
-        next_tok = 0
-        # stage index each in-flight token sits at
-        at: dict[int, int] = {}
-        while len(done) < n:
-            # admit new tokens while the pool has room (serial_in_order entry)
-            while next_tok < n and len(envs) < pool:
-                envs[next_tok] = self._env_of(toks[next_tok])
-                at[next_tok] = 0
-                next_tok += 1
-            # advance the *oldest* tokens first (keeps in-order completion)
-            for k in sorted(envs):
-                s = at[k]
-                envs[k] = self.stage_fns[s](envs[k])
-                at[k] = s + 1
-                if at[k] == S:
-                    done[k] = self._out_of(envs.pop(k))
-                    at.pop(k)
-        return [done[k] for k in range(n)]
-
     def run_sequential(self, tokens: Iterable[tuple | Any]) -> list[Any]:
         """No pipelining — the original binary's behavior (baseline)."""
         return [self(*t) if isinstance(t, tuple) else self(t) for t in tokens]
@@ -488,7 +449,7 @@ class BuiltPipeline:
                  microbatch: int = 1,
                  pad_microbatches: bool = False,
                  buckets: "Sequence[int] | None" = None,
-                 profiler: Any = None, stage_workers: bool = False,
+                 profiler: Any = None,
                  replicas: "Sequence[int] | None" = None,
                  devices: "Sequence[Sequence[int]] | None" = None,
                  inventory: Any = None, fault_injector: Any = None,
@@ -504,9 +465,9 @@ class BuiltPipeline:
         executor validates it (>= 1).  Executors built here share this
         pipeline's compiled (and vmapped) stage executables.  ``profiler``
         attaches a :class:`~repro.core.profiler.StageProfiler` for online
-        per-stage times; ``stage_workers`` runs stages on dedicated
-        threads (host-bound pipelines); ``replicas`` widens stages to the
-        given per-stage worker counts (TBB parallel filters — see
+        per-stage times; ``replicas`` runs each stage on the given number
+        of worker threads (all-ones: one thread a stage, for host-bound
+        pipelines; wider: TBB parallel filters — see
         :func:`repro.core.partition.assign_replicas`); ``devices`` pins
         each replica to a device ordinal of ``inventory`` (the plan's
         :attr:`~repro.core.partition.PipelinePlan.stage_devices`);
@@ -519,9 +480,8 @@ class BuiltPipeline:
         return PipelineExecutor.from_pipeline(
             self, max_in_flight=max_in_flight, microbatch=microbatch,
             pad_microbatches=pad_microbatches, buckets=buckets,
-            profiler=profiler, stage_workers=stage_workers,
-            replicas=replicas, devices=devices, inventory=inventory,
-            fault_injector=fault_injector,
+            profiler=profiler, replicas=replicas, devices=devices,
+            inventory=inventory, fault_injector=fault_injector,
             max_group_retries=max_group_retries,
             quarantine_after=quarantine_after,
             retry_budget_ms=retry_budget_ms,
@@ -532,10 +492,10 @@ class BuiltPipeline:
                   microbatch: int = 1) -> list[Any]:
         """Run a token stream through the asynchronous executor.
 
-        Unlike :meth:`run` (the synchronous wavefront), every stage of an
-        admitted token is issued immediately and the host blocks only when
-        the token pool is full or at final retirement.  Results arrive in
-        submission order, identical to :meth:`run`/:meth:`run_sequential`.
+        Every stage of an admitted token is issued immediately and the
+        host blocks only when the token pool is full or at final
+        retirement.  Results arrive in submission order, identical to
+        :meth:`run_sequential`.
         """
         return self.executor(max_in_flight=max_in_flight,
                              microbatch=microbatch).run(tokens)
@@ -579,15 +539,6 @@ class BuiltPipeline:
         return total
 
     # -- helpers ------------------------------------------------------------ #
-    def _validated_pool(self) -> int:
-        """Token-pool size; ``max_in_flight=0`` is an error, not "unset"."""
-        if self.max_in_flight is not None and self.max_in_flight < 1:
-            raise ValueError(
-                f"max_in_flight must be >= 1 (got {self.max_in_flight}); "
-                "use None for the default pool of n_stages + 1")
-        S = len(self.stage_fns)
-        return self.max_in_flight if self.max_in_flight is not None else S + 1
-
     def _env_of(self, args: Sequence[Any]) -> dict:
         if len(args) != len(self.graph_inputs):
             raise ValueError(f"expected {len(self.graph_inputs)} inputs, "

@@ -382,7 +382,7 @@ class DeviceInventory:
 
         ``probe`` is a zero-arg callable returning the NEW
         :class:`DeviceInventory` (default: :meth:`detect` — the real
-        re-probe; tests and fault benchmarks pass
+        re-probe; fault tests pass
         ``FaultInjector.surviving``).  Devices are matched by identity
         ``(platform, device_id)``, so a loss that re-densifies the
         ordinals still maps every survivor old→new in the returned

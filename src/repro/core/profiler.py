@@ -12,8 +12,8 @@ gets noisy neighbors).
 :class:`~repro.core.executor.PipelineExecutor` and fed per-stage wall times
 from its issue/retire hooks:
 
-* **threaded stage-worker mode** times every stage invocation exactly (each
-  stage runs to completion inside its own worker);
+* **ring workers** (``replicas=``) time every stage invocation exactly
+  (each stage runs to completion inside its own worker);
 * **async-dispatch mode** samples: every ``sample_every``-th token group is
   issued with a blocking barrier after each stage, so steady-state traffic
   pays the measurement cost only at the sampling rate.
@@ -60,8 +60,8 @@ class StageProfiler:
         group (1 = every group).  A sampled group is issued with a
         blocking barrier per stage — i.e. it loses its async overlap — so
         the default keeps sampling sparse (1 in 8); lower it only for
-        pipelines whose stages are host-bound anyway.  Threaded stage
-        workers ignore this — their timing is free.
+        pipelines whose stages are host-bound anyway.  Ring workers
+        ignore this — their timing is free.
     min_samples:
         Minimum per-stage samples before :meth:`measured_ms` (and hence
         re-planning) trusts the window.

@@ -9,8 +9,8 @@ and the 2×16×16 multi-pod mesh:
     print(compiled.cost_analysis())      # FLOPs/bytes for §Roofline
 
 plus a collective-bytes pass over the post-SPMD HLO (cost_analysis doesn't
-report collectives).  Results land in artifacts/dryrun/*.json for
-benchmarks/roofline.py and EXPERIMENTS.md §Dry-run.
+report collectives).  Results land in artifacts/dryrun/*.json
+(EXPERIMENTS.md §Dry-run).
 
 Usage:
     python -m repro.launch.dryrun --arch gemma3-12b --shape train_4k
@@ -107,8 +107,8 @@ def default_scan_chunks(n_layers: int) -> int:
 # We therefore compile two tiny *unrolled* variants (k1, k2 layers) on the
 # same mesh/shardings and extrapolate linearly in L:
 #     total(L) = C(k1) + (C(k2) - C(k1)) / (k2 - k1) * (L - k1)
-# Time-recurrence inner scans (rwkv/ssm) remain under-counted and get an
-# analytic correction in benchmarks/roofline.py (documented there).
+# Time-recurrence inner scans (rwkv/ssm) remain under-counted; no
+# correction is applied here.
 # --------------------------------------------------------------------------- #
 def probe_layer_counts(cfg) -> tuple[int, int]:
     if cfg.cross_attn_every:

@@ -315,7 +315,7 @@ def transformer_demo(lib: Any, params: dict) -> Callable:
     return app
 
 
-def init_recurrent_params(key: jax.Array, *, d: int = 64) -> dict:  # lint: allow-dead(traced-demo API exercised by benchmarks/tests)
+def init_recurrent_params(key: jax.Array, *, d: int = 64) -> dict:
     """Random weights for :func:`recurrent_demo` (RWKV shift + SSM scan)."""
     k1, k2 = jax.random.split(key)
     return {"mu": jax.random.uniform(k1, (d,), jnp.float32, 0.1, 0.9),
@@ -325,7 +325,7 @@ def init_recurrent_params(key: jax.Array, *, d: int = 64) -> dict:  # lint: allo
             "ln": jnp.zeros((d,), jnp.float32)}
 
 
-def recurrent_demo(lib: Any, params: dict) -> Callable:  # lint: allow-dead(traced-demo API exercised by benchmarks/tests)
+def recurrent_demo(lib: Any, params: dict) -> Callable:
     """Minimal RWKV/SSM-style block: shift-mix → norm → scan → residual."""
     def app(x: jax.Array) -> jax.Array:          # x: [T, d]
         h = lib.rwkv_shift(x, params["mu"])

@@ -168,7 +168,7 @@ class ElasticPlanner:
 
     @staticmethod
     def _cache_key(plan: PipelinePlan, replicas, max_in_flight, microbatch,
-                   jit, stage_workers, profiler, devices=None) -> tuple:
+                   jit, profiler, devices=None) -> tuple:
         """Executor-cache identity: plan shape + replicas + device pinning
         + executor config.
 
@@ -180,11 +180,11 @@ class ElasticPlanner:
         return (tuple(len(s.node_names) for s in plan.stages),
                 tuple(replicas) if replicas else None,
                 tuple(tuple(row) for row in devices) if devices else None,
-                max_in_flight, microbatch, jit, stage_workers, id(profiler))
+                max_in_flight, microbatch, jit, id(profiler))
 
     def _build_executor(self, plan: PipelinePlan, *, max_in_flight, microbatch,
-                        jit, profiler=None, stage_workers=False,
-                        replicas=None, devices=None) -> Any:
+                        jit, profiler=None, replicas=None,
+                        devices=None) -> Any:
         from repro.core.executor import PipelineExecutor
         from repro.core.pipeline import assign_placements, make_stage_fns
 
@@ -199,7 +199,6 @@ class ElasticPlanner:
                                 self.layer_ir.graph_outputs,
                                 max_in_flight=max_in_flight,
                                 microbatch=microbatch, profiler=profiler,
-                                stage_workers=stage_workers,
                                 replicas=replicas, devices=devices,
                                 inventory=self.inventory,
                                 fault_injector=self.fault_injector,
@@ -222,7 +221,6 @@ class ElasticPlanner:
     def executor_for(self, n_stages: int, *, max_in_flight: int | None = None,
                      microbatch: int = 1, jit: bool = True,
                      profiler: Any = None,
-                     stage_workers: bool = False,
                      worker_budget: "int | str | None" = None,
                      ) -> tuple[Any, bool]:
         """(executor, rebuilt) for a resource count of ``n_stages``.
@@ -248,14 +246,13 @@ class ElasticPlanner:
         plan = self.plan(n_stages)
         replicas, devices = self._widen(plan, worker_budget)
         key = self._cache_key(plan, replicas, max_in_flight, microbatch,
-                              jit, stage_workers, profiler, devices)
+                              jit, profiler, devices)
         if self._cached is not None and self._cached[0] == key \
                 and not getattr(self._cached[1], "closed", False):
             return self._cached[1], False
         ex = self._build_executor(plan, max_in_flight=max_in_flight,
                                   microbatch=microbatch, jit=jit,
                                   profiler=profiler,
-                                  stage_workers=stage_workers,
                                   replicas=replicas, devices=devices)
         self._cached = (key, ex)
         self._current_plan = plan
@@ -266,7 +263,6 @@ class ElasticPlanner:
                             max_stages: int | None = None,
                             max_in_flight: int | None = None,
                             microbatch: int = 1, jit: bool = True,
-                            stage_workers: bool = False,
                             min_gain: float | None = None,
                             margin: float | None = None,
                             min_samples: int | None = None,
@@ -484,10 +480,10 @@ class ElasticPlanner:
         devices = chosen.stage_devices if replicas is not None else None
         ex = self._build_executor(plan=chosen, max_in_flight=max_in_flight,
                                   microbatch=microbatch, jit=jit,
-                                  profiler=prof, stage_workers=stage_workers,
-                                  replicas=replicas, devices=devices)
+                                  profiler=prof, replicas=replicas,
+                                  devices=devices)
         key = self._cache_key(chosen, replicas, max_in_flight, microbatch,
-                              jit, stage_workers, prof, devices)
+                              jit, prof, devices)
         self._cached = (key, ex)
         self._current_plan = chosen
         self.rebuilds += 1
@@ -545,7 +541,6 @@ class ElasticPlanner:
                                    profiler: Any = None, stats: Any = None,
                                    max_in_flight: int | None = None,
                                    microbatch: int = 1, jit: bool = True,
-                                   stage_workers: bool = False,
                                    worker_budget: "int | str | None" = None,
                                    new_profiler: Any = None) -> ReplanDecision:
         """Survivors-only re-plan after a device loss/gain.
@@ -665,10 +660,10 @@ class ElasticPlanner:
             prof = profiler.clone_for(cand.n_stages)
         ex = self._build_executor(plan=cand, max_in_flight=max_in_flight,
                                   microbatch=microbatch, jit=jit,
-                                  profiler=prof, stage_workers=stage_workers,
-                                  replicas=replicas, devices=devices)
+                                  profiler=prof, replicas=replicas,
+                                  devices=devices)
         key = self._cache_key(cand, replicas, max_in_flight, microbatch,
-                              jit, stage_workers, prof, devices)
+                              jit, prof, devices)
         self._cached = (key, ex)
         self._current_plan = cand
         self.rebuilds += 1

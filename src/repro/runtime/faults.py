@@ -32,7 +32,7 @@ both with one scriptable harness:
   ``DeviceInventory.refresh(probe=...)``.
 
 The injector is also scriptable *after* construction (``lose_device`` on a
-live injector), which is how benchmarks pull a device out from under a
+live injector), which is how tests pull a device out from under a
 serving loop mid-run.
 """
 from __future__ import annotations
@@ -145,7 +145,7 @@ class FaultInjector:
     live behind one lock; the fault decision for an invocation depends
     only on its count, so concurrent replicas see a deterministic
     schedule.  Counters (``injected``/``slowed``/``device_faults``) make
-    the injected load auditable from benchmarks.
+    the injected load auditable from tests.
     """
 
     def __init__(self, plan: FaultPlan | None = None):
@@ -168,7 +168,7 @@ class FaultInjector:
         inj._hook = hook
         return inj
 
-    # -- live scripting (benchmarks pull devices mid-run) -------------------- #
+    # -- live scripting (tests pull devices mid-run) ------------------------- #
     def lose_device(self, ordinal: int, *, after_calls: int = 0) -> None:
         """Script a device loss on a LIVE injector (counted from the calls
         already placed on the ordinal)."""

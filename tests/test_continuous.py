@@ -276,6 +276,59 @@ def test_continuous_stress_survives_transient_faults():
     assert xst.retries + xst.quarantined >= 1      # the chaos actually landed
 
 
+def _serve_two_steps(continuous: bool, xs: np.ndarray) -> tuple:
+    """Serve two decode steps of each session in ``xs`` ([n, 2, IO]).  The
+    first step of session 0 parks the stage-0 worker, and each later
+    first step is submitted only after the one before it was admitted, so
+    in continuous mode the groups behind the parked one stay open and the
+    later arrivals join their padding seats."""
+    pool = KVSlotPool(len(xs), 4, {"k": (IO,)})
+    gate, entered = threading.Event(), threading.Event()
+    ex = _executor(_stage_fns(pool, gate=gate, entered=entered),
+                   open_groups=continuous)
+    slots = [pool.alloc() for _ in xs]
+    outs = []
+    with RequestQueueServer(ex, max_batch=MB, max_wait_ms=2.0,
+                            continuous=continuous) as srv:
+        reqs = [srv.submit(xs[0, 0], slots[0])]
+        assert entered.wait(10.0)
+        for x, slot in zip(xs[1:, 0], slots[1:]):
+            reqs.append(srv.submit(x, slot))
+            t_end = time.perf_counter() + 10.0
+            while ex.stats().tokens_admitted < len(reqs):
+                assert time.perf_counter() < t_end, "request never admitted"
+                time.sleep(0.0005)
+        gate.set()
+        outs.append([np.asarray(r.wait(10.0)) for r in reqs])
+        reqs = [srv.submit(x, slot) for x, slot in zip(xs[:, 1], slots)]
+        outs.append([np.asarray(r.wait(10.0)) for r in reqs])
+    st = ex.stats()
+    ex.close()
+    for slot in slots:
+        pool.free(slot)
+    pool.check_no_leaks()
+    return outs, st
+
+
+def test_boundary_and_continuous_admission_give_identical_outputs():
+    """Batch-boundary and continuous admission over the same stateful
+    pipeline return bitwise the same decode outputs; only the continuous
+    run admits arrivals at the seam."""
+    xs = np.random.default_rng(11).standard_normal((5, 2, IO)).astype(
+        np.float32)
+    boundary, st_b = _serve_two_steps(False, xs)
+    continuous, st_c = _serve_two_steps(True, xs)
+    assert st_b.seam_joins == 0 and st_c.seam_joins > 0
+    assert st_b.out_of_order_retired == st_c.out_of_order_retired == 0
+    for step in range(2):
+        for sess in range(len(xs)):
+            np.testing.assert_array_equal(continuous[step][sess],
+                                          boundary[step][sess])
+            hist = [xs[sess, 0] + np.float32(1.0)] if step else []
+            np.testing.assert_array_equal(
+                boundary[step][sess], _expected_step(hist, xs[sess, step]))
+
+
 # --------------------------------------------------------------------------- #
 # on_finish: exactly once, on every terminal path
 # --------------------------------------------------------------------------- #

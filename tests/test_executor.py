@@ -81,12 +81,22 @@ def test_async_run_matches_sequential(mkdb, mkapp, pool):
 
 
 def test_async_matches_sync_wavefront_run():
+    """Async issue and the serial threaded rings (all-ones ``replicas``,
+    one worker a stage, each stage run to completion before the next)
+    give the sequential results, in order, on a branching graph."""
     db = _branch_db()
     app = _branch_app(Library(db))
     pipe = _pipe(db, app)
     toks = [jnp.full((4,), float(i)) for i in range(5)]
-    for g, w in zip(pipe.run_async(toks), pipe.run(toks)):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-6)
+    want = pipe.run_sequential(toks)
+    rings = pipe.executor(replicas=[1] * len(pipe.stage_fns))
+    serial = rings.run(toks)
+    rings.close()
+    for got in (pipe.run_async(toks), serial):
+        assert len(got) == len(want)
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=1e-6)
 
 
 # --------------------------------------------------------------------------- #
@@ -111,7 +121,7 @@ def test_max_in_flight_zero_rejected_everywhere():
     pipe = _pipe(db, app)
     pipe.max_in_flight = 0
     with pytest.raises(ValueError, match="max_in_flight"):
-        pipe.run([jnp.ones(4)])
+        pipe.run_async([jnp.ones(4)])
     with pytest.raises(ValueError, match="max_in_flight"):
         pipe.executor()
     with pytest.raises(ValueError, match="max_in_flight"):
@@ -120,7 +130,7 @@ def test_max_in_flight_zero_rejected_everywhere():
     with pytest.raises(ValueError, match="max_in_flight"):
         pipe.run_async([jnp.ones(4)], max_in_flight=-2)
     pipe.max_in_flight = None                    # None = default, still fine
-    assert len(pipe.run([jnp.ones(4)])) == 1
+    assert len(pipe.run_async([jnp.ones(4)])) == 1
 
 
 # --------------------------------------------------------------------------- #
@@ -530,7 +540,7 @@ def _scale(env):
 
 
 @pytest.mark.parametrize("mode, width", [
-    ({}, 7), ({"stage_workers": True}, 8), ({"replicas": [1, 2]}, 9)],
+    ({}, 7), ({"replicas": [1, 1]}, 8), ({"replicas": [1, 2]}, 9)],
     ids=["async", "threaded", "replicated"])
 @pytest.mark.parametrize("buckets", [None, (1, 2, 4)],
                          ids=["pad-to-max", "buckets"])

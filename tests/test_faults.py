@@ -226,6 +226,45 @@ def test_repeated_errors_quarantine_the_replica():
     assert st.out_of_order_retired == 0 and st.tokens_retired == 9
 
 
+def test_jitted_pipeline_transients_retry_bit_identical():
+    """Transients on the widened stage of the jitted (buffer-donating)
+    Harris pipeline are retried mid-stream, and every result is bitwise
+    the fault-free run's: injection fires before the stage body, so a
+    retry never re-runs a half-donated buffer."""
+    import jax
+
+    from repro.core import assign_replicas, courier_offload
+    from repro.core.tracer import Library
+    from repro.models.harris import corner_harris_demo, make_harris_db
+
+    db = make_harris_db(with_hw=False)
+    app = corner_harris_demo(Library(db))
+    frames = [jax.random.uniform(jax.random.PRNGKey(i), (32, 48, 3)) * 255
+              for i in range(8)]
+    pipe = courier_offload(app, frames[0], db=db, prefer_hw=False).pipeline
+    plan = assign_replicas(pipe.plan, pipe.ir, worker_budget=8)
+    wide = max(range(plan.n_stages), key=lambda s: plan.replicas[s])
+    assert plan.replicas[wide] > 1
+    clean = pipe.executor(replicas=plan.replicas)
+    clean.warmup(frames[0])
+    want = clean.run(frames)
+    clean.close()
+
+    inj = FaultPlan().build()          # empty: warmup runs fault-free
+    ex = pipe.executor(replicas=plan.replicas, fault_injector=inj,
+                       quarantine_after=3)
+    ex.warmup(frames[0])
+    base = inj.stage_calls(wide)       # script the faults mid-stream
+    inj.plan.transient(wide, at_calls=[base + 2, base + 5])
+    got = ex.run(frames)
+    st = ex.stats()
+    ex.close()
+    assert inj.injected == 2 and st.retries == 2
+    assert st.out_of_order_retired == 0 and st.tokens_retired == 8
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
 def test_unreplicated_stage_error_fails_the_group():
     # stage 1 has no sibling: the injected fault errors that group only,
     # in order, and the pool is not leaked
@@ -404,7 +443,7 @@ def _slow_executor(ms=30.0, max_in_flight=2):
     def slow(env):
         time.sleep(ms / 1e3)
         return {"y": np.asarray(env["x"]) * 2.0}
-    return PipelineExecutor([slow], ["x"], ["y"], stage_workers=True,
+    return PipelineExecutor([slow], ["x"], ["y"], replicas=[1],
                             max_in_flight=max_in_flight)
 
 

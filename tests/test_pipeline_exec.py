@@ -44,7 +44,7 @@ def test_pipeline_semantics_random_chains(chain, n_tokens, n_threads, pool):
     pipe = PipelineGenerator(db).generate(ir, n_threads=n_threads)
     pipe.max_in_flight = pool
     toks = [jnp.full((4,), float(i + 1)) for i in range(n_tokens)]
-    got = pipe.run(toks)
+    got = pipe.run_async(toks)
     want = [app(t) for t in toks]
     for g, w in zip(got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-6)

@@ -89,11 +89,11 @@ def test_profiler_sampling_tick():
 
 
 def test_profiler_converges_on_injected_stage_times():
-    """Measured medians track the injected sleeps (threaded stage workers)."""
+    """Measured medians track the injected sleeps (every group sampled)."""
     planner = _sim_planner(n_nodes=6, base_ms=2.0)
-    prof = StageProfiler(3, min_samples=4)
+    prof = StageProfiler(3, min_samples=4, sample_every=1)
     ex, _ = planner.executor_for(3, max_in_flight=8, jit=False,
-                                 profiler=prof, stage_workers=True)
+                                 profiler=prof)
     toks = [np.full((4,), float(i)) for i in range(12)]
     ex.run(toks)
     for k in range(3):
@@ -359,11 +359,11 @@ def test_replan_detects_gradual_drift_against_model_baseline():
 
 def test_executor_for_never_serves_a_closed_executor():
     planner = _sim_planner(n_nodes=4, base_ms=1.0)
-    ex, rebuilt = planner.executor_for(2, jit=False, stage_workers=True)
+    ex, rebuilt = planner.executor_for(2, jit=False)
     assert rebuilt
     ex.run([np.zeros(4)])
     ex.close()
-    ex2, rebuilt = planner.executor_for(2, jit=False, stage_workers=True)
+    ex2, rebuilt = planner.executor_for(2, jit=False)
     assert rebuilt and ex2 is not ex          # closed executor not cached out
     out = ex2.run([np.zeros(4)])              # and the fresh one works
     np.testing.assert_allclose(np.asarray(out[0]), np.full(4, 4.0))

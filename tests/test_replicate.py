@@ -121,7 +121,7 @@ def _jnp_fns():
 
 def test_replicated_results_match_serial_and_retire_in_order():
     toks = [(jnp.full((4,), float(i)),) for i in range(20)]
-    ser = PipelineExecutor(_jnp_fns(), ["x"], ["y"], stage_workers=True)
+    ser = PipelineExecutor(_jnp_fns(), ["x"], ["y"], replicas=[1, 1, 1])
     want = ser.run(toks)
     ser.close()
     rep = PipelineExecutor(_jnp_fns(), ["x"], ["y"], replicas=[2, 3, 2])
@@ -205,7 +205,7 @@ def test_ordered_retirement_under_random_replica_jitter():
         return {"y": np.asarray(env["x"]) - 5.0}
 
     toks = [(np.full((3,), float(i)),) for i in range(40)]
-    ser = PipelineExecutor([jittery, tail], ["x"], ["y"], stage_workers=True)
+    ser = PipelineExecutor([jittery, tail], ["x"], ["y"], replicas=[1, 1])
     want = ser.run(toks)
     ser.close()
     rep = PipelineExecutor([jittery, tail], ["x"], ["y"], replicas=[4, 3],
@@ -311,9 +311,9 @@ def _dominant_planner(times=(0.5, 6.0, 0.5, 0.5)):
 
 def test_replan_widen_beats_rebalance_on_dominant_stage():
     planner = _dominant_planner()
-    prof = StageProfiler(4, min_samples=4)
+    prof = StageProfiler(4, min_samples=4, sample_every=1)
     ex, _ = planner.executor_for(4, max_in_flight=10, jit=False,
-                                 profiler=prof, stage_workers=True)
+                                 profiler=prof)
     boundaries0 = [list(s.node_names) for s in planner.current_plan.stages]
     toks = [np.full((4,), float(i)) for i in range(12)]
     ex.run(toks)
@@ -333,9 +333,9 @@ def test_replan_widen_beats_rebalance_on_dominant_stage():
 
 def test_replan_widened_plan_is_stable_not_flapping():
     planner = _dominant_planner()
-    prof = StageProfiler(4, min_samples=4)
+    prof = StageProfiler(4, min_samples=4, sample_every=1)
     ex, _ = planner.executor_for(4, max_in_flight=10, jit=False,
-                                 profiler=prof, stage_workers=True)
+                                 profiler=prof)
     ex.run([np.full((4,), float(i)) for i in range(12)])
     d = planner.replan_from_profile(prof, worker_budget=8, jit=False)
     assert d.replanned and d.widened

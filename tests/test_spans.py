@@ -73,14 +73,14 @@ def test_singletons_are_not_padded():
 
 def test_pool_wait_grows_only_when_the_pool_is_full():
     roomy = PipelineExecutor([_slow_stage], ["x"], ["x"], max_in_flight=4,
-                             stage_workers=True)
+                             replicas=[1])
     hs = [roomy.submit(_tok(i)) for i in range(3)]
     [h.result() for h in hs]
     assert roomy.stats().pool_wait_ms == 0.0
     roomy.close()
 
     full = PipelineExecutor([_slow_stage], ["x"], ["x"], max_in_flight=1,
-                            stage_workers=True)
+                            replicas=[1])
     hs = [full.submit(_tok(i)) for i in range(3)]
     [h.result() for h in hs]
     # the 2nd and 3rd admissions each waited for a 30 ms stage
